@@ -33,19 +33,40 @@ SPECS = [
 ]
 
 
+# two of the paper's matrices at their published SuiteSparse sizes
+# (rows, nonzeros): m133-b3 is generated exactly (4 nnz per row); soc is
+# a power-law stand-in of soc-Epinions1's size with SPECS' soc skew
+PUBLISHED = {"m133-b3": (200_200, 800_800), "soc": (75_879, 508_837)}
+
+
+def _regular4(rows: int, seed: int) -> CSR:
+    """Exactly 4 nnz per row, random columns — m133-b3's structure."""
+    rng = np.random.default_rng(seed)
+    r = np.repeat(np.arange(rows), 4)
+    c = rng.integers(0, rows, rows * 4)
+    v = rng.standard_normal(rows * 4).astype(np.float32)
+    return csr_from_coo(r, c, v, (rows, rows))
+
+
 def build(name: str) -> CSR:
     for n, pattern, rows, dens, skew in SPECS:
         if n == name:
             if n == "m133-b3":
                 # the paper's m133-b3 has exactly 4 nnz/row, zero variance
-                rng = np.random.default_rng(7)
-                r = np.repeat(np.arange(rows), 4)
-                c = rng.integers(0, rows, rows * 4)
-                v = rng.standard_normal(rows * 4).astype(np.float32)
-                return csr_from_coo(r, c, v, (rows, rows))
+                return _regular4(rows, seed=7)
             return random_sparse(rows, rows, dens, seed=abs(hash(n)) % 2**31,
                                  pattern=pattern, skew=skew or 1.5)
     raise KeyError(name)
+
+
+def build_published(name: str, seed: int = 0) -> CSR:
+    """``name`` at its published size (see ``PUBLISHED``), from ``seed``."""
+    rows, nnz = PUBLISHED[name]
+    if name == "m133-b3":
+        return _regular4(rows, seed)
+    skew = next(s[4] for s in SPECS if s[0] == name)
+    return random_sparse(rows, rows, nnz / rows ** 2, seed=seed,
+                         pattern="powerlaw", skew=skew)
 
 
 def names(limit=None):

@@ -29,6 +29,7 @@ import numpy as np
 
 from benchmarks import datasets
 from repro.core import spgemm_engines as sg
+from repro.launch.compile_cache import enable_compile_cache
 
 # rows of the section currently running; flushed to BENCH_<section>.json
 _ROWS: list[dict] = []
@@ -652,7 +653,14 @@ def serve_bench(fast=False):
     # runs cold so the SIGKILL lands inside the measured traffic.  On a
     # single-core runner the w2/w4 rows measure dispatch overhead, not
     # parallel speedup — the availability fraction is the real gate.
-    from repro.runtime.coordinator import ProcessCoordinator
+    from repro.runtime.coordinator import (ChipContention,
+                                           ProcessCoordinator,
+                                           check_one_process_per_chip)
+    try:
+        check_one_process_per_chip(4)
+    except ChipContention as e:
+        print(f"# serve.multiproc / serve.async.w*: not run: {e}")
+        return
     n_mp = 24 if fast else 48
 
     def _mp_traffic(pool, path, seed):
@@ -775,6 +783,7 @@ def main() -> None:
     ap.add_argument("--limit", type=int, default=None,
                     help="first N matrices only")
     args = ap.parse_args()
+    enable_compile_cache()
     mats = None
     for name in args.which:
         fn = ALL[name]

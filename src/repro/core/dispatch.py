@@ -1544,7 +1544,8 @@ def _spz_batched(A: BatchedCSR, B: BatchedCSR, *, R: int = 16,
     out_k = {it: np.empty(0, np.int32) for it in items}
     out_v = {it: np.empty(0, np.float32) for it in items}
     if driver == "fused":
-        mats = (A.indptr, A.indices, A.data, B.indptr, B.indices, B.data)
+        mats = sg.fused_operands(A.indptr, A.indices, A.data,
+                                 B.indptr, B.indices, B.data)
         for g0 in range(0, len(items), S):
             group = items[g0:g0 + S]
             plens = np.array([work[ln][r] for ln, r in group], np.int64)

@@ -363,13 +363,34 @@ def merge_tree_host(parts, R, backend, stats: SpzStats, cap_s=None):
 # device-resident (fused) spz pipeline
 # ---------------------------------------------------------------------------
 
+def fused_operands(a_indptr, a_idx, a_val, b_indptr, b_idx, b_val):
+    """The fused pipeline's per-product operands: the six (batch, ...)
+    stacked CSR arrays plus ``wcum`` (batch, nnz_cap + 1) int32, the
+    exclusive prefix of products expanded per A entry of each lane.
+
+    ``wcum`` depends only on the operands, so it is computed once per
+    product on the host rather than inside every bucket's program (where
+    a device cumsum over nnz elements also costs tens of seconds of TPU
+    compile per bucket shape)."""
+    ip, ix, bp = (np.asarray(x) for x in (a_indptr, a_idx, b_indptr))
+    blen = np.diff(bp, axis=1)
+    t_ok = np.arange(ix.shape[1])[None, :] < ip[:, -1:]
+    w = np.where(t_ok, np.take_along_axis(blen, np.where(t_ok, ix, 0),
+                                          axis=1), 0)
+    wcum = np.concatenate([np.zeros((ix.shape[0], 1), np.int64),
+                           np.cumsum(w, axis=1)], axis=1)
+    return (a_indptr, a_idx, a_val, b_indptr, b_idx, b_val,
+            jnp.asarray(wcum.astype(np.int32)))
+
+
 def _fused_expand(row_ids, lane_ids, a_indptr, a_idx, a_val,
-                  b_indptr, b_idx, b_val, L: int):
+                  b_indptr, b_idx, b_val, wcum0, L: int):
     """Device-side expansion: per-stream padded partial products.
 
     row_ids/lane_ids: (S,) int32 — stream s expands output row
     ``row_ids[s]`` of batch lane ``lane_ids[s]`` (row_ids < 0 marks
-    padding streams).  Matrix arrays are (batch, ...) stacked.  Returns
+    padding streams).  Matrix arrays are (batch, ...) stacked, with the
+    per-lane work prefix ``wcum0`` of :func:`fused_operands`.  Returns
     (keys (S, L), vals (S, L), plens (S,)) with EMPTY/0 padding — the
     device replacement for the host ``expand_group`` + chunk-buffer
     marshaling.
@@ -380,14 +401,6 @@ def _fused_expand(row_ids, lane_ids, a_indptr, a_idx, a_val,
     valid_s = row_ids >= 0
     lane = jnp.clip(lane_ids.astype(jnp.int32), 0, Bn - 1)
     row = jnp.clip(row_ids.astype(jnp.int32), 0, n_rows1 - 2)
-    # per-lane work geometry: w[t] = |B row a_idx[t]| for valid entries
-    blen = (b_indptr[:, 1:] - b_indptr[:, :-1]).astype(jnp.int32)
-    nnz = a_indptr[:, -1]
-    t_ok = jnp.arange(nnz_cap, dtype=jnp.int32)[None, :] < nnz[:, None]
-    j_all = jnp.where(t_ok, a_idx, 0)
-    w = jnp.where(t_ok, jnp.take_along_axis(blen, j_all, axis=1), 0)
-    wcum0 = jnp.concatenate(
-        [jnp.zeros((Bn, 1), jnp.int32), jnp.cumsum(w, axis=1)], axis=1)
     # flatten lanes onto one monotone axis so one searchsorted serves the
     # whole batch: lane l lives at offset l * (max total work + 1)
     M = jnp.max(wcum0[:, -1]) + 1
@@ -415,7 +428,7 @@ def _fused_expand(row_ids, lane_ids, a_indptr, a_idx, a_val,
 
 
 def _fused_bucket_impl(row_ids, lane_ids, a_indptr, a_idx, a_val,
-                       b_indptr, b_idx, b_val, R: int, L: int,
+                       b_indptr, b_idx, b_val, wcum0, R: int, L: int,
                        backend: str):
     """One work bucket of a lock-step group, fully device-resident:
     expansion, chunk sort, and the whole zip-merge tree chained under a
@@ -423,10 +436,14 @@ def _fused_bucket_impl(row_ids, lane_ids, a_indptr, a_idx, a_val,
     rounds carries the per-(round, pair) merge counters (see
     kernels/merge_tree.py zip_merge_tree detailed mode)."""
     keys, vals, plens = _fused_expand(row_ids, lane_ids, a_indptr, a_idx,
-                                      a_val, b_indptr, b_idx, b_val, L)
+                                      a_val, b_indptr, b_idx, b_val, wcum0,
+                                      L)
     return kvstream.fused_sort_merge(keys, vals, plens, R=R,
                                      backend=backend, detailed=True)
 
+
+# smallest stream count a bucket is padded to
+MIN_BUCKET_STREAMS = 8
 
 # one compiled pipeline per static (N, L, R) bucket + matrix capacity
 _fused_bucket = functools.partial(
@@ -446,7 +463,7 @@ def fused_process_group(items, plens, mats, R, backend, stats: SpzStats,
     """Run one lock-step group of work items through the fused pipeline.
 
     items: [(lane, row)] output rows of the group; plens: per-item
-    product counts; mats: six (batch, ...) stacked CSR arrays; results
+    product counts; mats: :func:`fused_operands`; results
     land in out_k/out_v keyed by (lane, row), or — when ``coo`` is given
     instead — as vectorized (rows, cols, vals) triples appended to it
     (the single-matrix fast path: no per-row slicing).
@@ -489,7 +506,9 @@ def fused_process_group(items, plens, mats, R, backend, stats: SpzStats,
     zip_elems = 0
     for C_b in sorted(buckets):
         idxs = buckets[C_b]
-        Nb = 1 << max(0, len(idxs) - 1).bit_length()
+        # pow2 stream counts, at least MIN_BUCKET_STREAMS, bound the
+        # number of compiled bucket shapes; padding streams do no work
+        Nb = max(MIN_BUCKET_STREAMS, 1 << max(0, len(idxs) - 1).bit_length())
         row_ids = np.full(Nb, -1, np.int32)
         lane_ids = np.zeros(Nb, np.int32)
         for t, ix in enumerate(idxs):
@@ -566,8 +585,8 @@ def _spz_fused_driver(A, B, R, S, order, work, backend, stats):
     SpzStats counts come back as device counters (wall-clock attribution
     collapses into t_sort)."""
     coo: list = []
-    mats = (A.indptr[None], A.indices[None], A.data[None],
-            B.indptr[None], B.indices[None], B.data[None])
+    mats = fused_operands(A.indptr[None], A.indices[None], A.data[None],
+                          B.indptr[None], B.indices[None], B.data[None])
     for g0 in range(0, A.n_rows, S):
         rows = order[g0:g0 + S]
         items = [(0, int(i)) for i in rows]

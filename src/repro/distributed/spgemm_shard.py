@@ -14,7 +14,8 @@ each device's lane group in parallel:
     idiom as ``models/moe.py``), every device vmapping the ESC core
     over its local lane shard under one compilation;
   * **spz family** (host-orchestrated pipelines): the same balanced
-    assignment executed group-at-a-time through the batched drivers —
+    assignment executed group-at-a-time through the batched drivers,
+    device d's lane group placed on and run by mesh device d —
     per-stream payloads are independent of which streams share a kernel
     issue (see ``core/spgemm.py``), so splitting the batch cannot change
     results.
@@ -178,16 +179,14 @@ def _permute_to_slots(A: BatchedCSR, sp: ShardPlan) -> BatchedCSR:
 def _sharded_esc_fn(mesh, cap_products: int, n_rows: int, n_cols: int):
     """One jitted shard_map per (mesh, static capacities): each device
     vmaps the ESC core over its local lane shard."""
-    from jax.experimental.shard_map import shard_map
-
     def local(ip, ix, d, bip, bix, bd):
         return jax.vmap(sg.esc_core_impl,
                         in_axes=(0, 0, 0, 0, 0, 0, None, None, None))(
             ip, ix, d, bip, bix, bd, cap_products, n_rows, n_cols)
 
     spec = P("lanes")
-    return jax.jit(shard_map(local, mesh=mesh, in_specs=(spec,) * 6,
-                             out_specs=(spec,) * 5))
+    return jax.jit(jax.shard_map(local, mesh=mesh, in_specs=(spec,) * 6,
+                                 out_specs=(spec,) * 5))
 
 
 def _execute_esc_sharded(sp: ShardPlan, A: BatchedCSR, B: BatchedCSR) -> list:
@@ -214,16 +213,19 @@ def _execute_esc_sharded(sp: ShardPlan, A: BatchedCSR, B: BatchedCSR) -> list:
     return outs
 
 
-def _lane_select(A: BatchedCSR, idx: np.ndarray) -> BatchedCSR:
-    return BatchedCSR(A.indptr[idx], A.indices[idx], A.data[idx],
-                      A.valid[idx], A.shape)
+def _lane_select(A: BatchedCSR, idx: np.ndarray, device) -> BatchedCSR:
+    """Lanes ``idx`` of a batch, committed to ``device``."""
+    return BatchedCSR(*jax.device_put(
+        (A.indptr[idx], A.indices[idx], A.data[idx], A.valid[idx]), device),
+        A.shape)
 
 
 def _execute_groups(sp: ShardPlan, A: BatchedCSR, B: BatchedCSR, *,
                     dead: Optional[set] = None,
                     max_worker_restarts: int = 3) -> list:
     """Host-orchestrated engines: run one device group at a time through
-    the batched driver (same plan kwargs, so same static shapes).
+    the batched driver (same plan kwargs, so same static shapes), group
+    d's operands committed to mesh device d and its kernels issued there.
 
     Worker supervision (the serving-flush generalization of
     ``runtime/fault.py::run_resilient``'s restart loop): a device group
@@ -240,11 +242,15 @@ def _execute_groups(sp: ShardPlan, A: BatchedCSR, B: BatchedCSR, *,
     outs: list = [None] * A.batch
     lane_ok = np.asarray(A.valid) & np.asarray(B.valid)
     dead = set() if dead is None else set(dead)
+    devices = sp.mesh.devices.reshape(-1)
 
     def run(lanes: list, device: int) -> None:
         fi.fire("shard.worker", device=device, engine=sp.base.engine)
         idx = np.asarray(lanes)
-        sub = driver(_lane_select(A, idx), _lane_select(B, idx), **kw)
+        dev = devices[device]
+        with jax.default_device(dev):
+            sub = driver(_lane_select(A, idx, dev), _lane_select(B, idx, dev),
+                         **kw)
         for j, i in enumerate(lanes):
             outs[i] = sub[j]
 

@@ -28,8 +28,10 @@ Registered instances:
 
   ``xla``     pure-jnp oracles jitted as XLA computations (the driver
               workhorse off-TPU)
-  ``pallas``  ``pl.pallas_call`` kernels (interpret mode automatically
-              off-TPU): the native chunk-sort, the native
+  ``pallas``  ``pl.pallas_call`` kernels, compiled by Mosaic on a TPU
+              and run in interpret mode everywhere else (the one place
+              that choice is made is :func:`_interpret` below): the
+              native chunk-sort, the native
               ``merge_partitions`` bitonic-merge kernel, and the
               single-kernel fused bucket pipeline (chunks stay in VMEM
               across merge rounds) — all bit-identical to ``xla``
@@ -51,7 +53,6 @@ from repro.kernels.chunk_sort import chunk_sort_pallas
 from repro.kernels.fused_bucket import fused_bucket_pallas
 from repro.kernels.merge_partitions import merge_partitions_pallas
 from repro.kernels.stream_merge import stream_merge_pallas
-from repro.kernels.stream_sort import stream_sort_pallas
 
 
 def on_tpu() -> bool:
@@ -138,17 +139,21 @@ _sort_ref = jax.jit(ref.stream_sort_ref)
 _merge_ref = jax.jit(ref.stream_merge_ref)
 
 
+def _interpret() -> bool:
+    """The Pallas kernels compile for the chip on a TPU host; anywhere
+    else (the CPU test host) they run in interpret mode.  The kernel
+    wrappers default to compiled, so this is the only place interpret
+    mode is chosen."""
+    return not on_tpu()
+
+
 def _pallas_chunk_sort(keys, vals, lens):
-    return chunk_sort_pallas(keys, vals, lens, interpret=not on_tpu())
-
-
-def _pallas_stream_sort(keys, vals, lens):
-    return stream_sort_pallas(keys, vals, lens, interpret=not on_tpu())
+    return chunk_sort_pallas(keys, vals, lens, interpret=_interpret())
 
 
 def _pallas_stream_merge(ka, va, la, kb, vb, lb):
     return stream_merge_pallas(ka, va, la, kb, vb, lb,
-                               interpret=not on_tpu())
+                               interpret=_interpret())
 
 
 def _pallas_merge_partitions(ka, va, la, kb, vb, lb, *, R,
@@ -156,14 +161,14 @@ def _pallas_merge_partitions(ka, va, la, kb, vb, lb, *, R,
     return merge_partitions_pallas(ka, va, la, kb, vb, lb, R=R,
                                    pair_streams=pair_streams,
                                    with_counters=with_counters,
-                                   interpret=not on_tpu())
+                                   interpret=_interpret())
 
 
 def _pallas_fused_bucket(keys, vals, plens, *, R, with_counters=True,
                          detailed=False):
     return fused_bucket_pallas(keys, vals, plens, R=R,
                                with_counters=with_counters,
-                               detailed=detailed, interpret=not on_tpu())
+                               detailed=detailed, interpret=_interpret())
 
 
 register_backend(
@@ -177,12 +182,14 @@ register_backend(
 register_backend(
     name="pallas",
     chunk_sort=_pallas_chunk_sort,
-    stream_sort=_pallas_stream_sort,
+    # one mssort issue over S streams is one chunk-sort issue over S chunks
+    stream_sort=_pallas_chunk_sort,
     stream_merge=_pallas_stream_merge,
     merge_partitions=_pallas_merge_partitions,
     fused_bucket=_pallas_fused_bucket,
     needs_tpu_for_perf=True,
-    description="pl.pallas_call kernels (interpret mode off-TPU); the "
+    description="pl.pallas_call kernels (Mosaic on TPU, interpret mode "
+                "elsewhere); the "
                 "native chunk-sort, bitonic merge_partitions, and the "
                 "single-kernel fused bucket pipeline (VMEM-resident "
                 "merge tree)")

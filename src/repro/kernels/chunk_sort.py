@@ -1,34 +1,31 @@
 """Pallas TPU kernel: the fused pipeline's chunk-sort stage.
 
-Sorts ALL (N, R) = (S*C, R) chunks of a work bucket in one ``pallas_call``
-issue — the sort stage ``chunk_sort_partitions`` feeds into the
-device-resident zip-merge tree.  Unlike ``stream_sort_pallas`` (the
-host-tier mssort kernel, whose duplicate accumulation is a log-step tree
-scan), this kernel is **bit-identical** to the XLA oracle
+Sorts ALL (N, R) chunks of a work bucket in one ``pallas_call`` issue —
+the sort stage ``chunk_sort_partitions`` feeds into the device-resident
+zip-merge tree.  The kernel is **bit-identical** to the XLA oracle
 (``ref.stream_sort_ref`` / ``merge_tree.sort_chunks_linear``):
 
-  * the sort is a bitonic network over the R lane dimension made *stable*
-    by comparing (key, source-lane) pairs lexicographically
-    (``_network.bitonic_sort_stable``), so ties keep product order
-    exactly like a stable argsort;
+  * the sort is a bitonic network over each R-wide chunk made *stable* by
+    comparing (key, source-lane) pairs lexicographically
+    (``_network.sort_segments``), so ties keep product order exactly like
+    a stable argsort;
   * duplicate values accumulate in a left-to-right linear association
     (an R-step sequential run prefix, the same adds in the same order as
     ``segment_sum``'s index-order accumulation) — a tree reduction would
     round differently;
-  * the compress pass routes each surviving tuple through a one-hot MXU
-    matmul with exactly one unit coefficient per output lane, which moves
-    keys (16-bit split) and values bit-exactly.
+  * the compress pass (``_network.compact``) moves each surviving tuple,
+    it never recombines one.
 
 Invariants: R must be a power of two (bitonic network width); input keys
-beyond ``lens`` may be garbage (they are masked to EMPTY first); valid
-keys are < 2**31 - 1 so EMPTY is a strict upper bound and the 16-bit
-compress split is exact.
+beyond ``lens`` may be garbage (the wrapper masks them to EMPTY first);
+valid keys are < 2**31 - 1 so EMPTY is a strict upper bound.
 
-One program sorts a (BLOCK_N, R) tile held in VMEM; the grid walks blocks
-of chunks, so a whole bucket's S*C chunks are one kernel issue.  The tile
-body is exposed as :func:`sort_tile` so the single-kernel fused bucket
-pipeline (``kernels/fused_bucket.py``) can run the identical sort stage
-inside its own ``pallas_call``.
+Chunks are laid out in ``_network``'s (T, 128) tiles — 128 / R chunks
+per row for R < 128 — and the grid walks blocks of them, so a whole
+bucket's S*C chunks are one kernel issue.  The tile body is exposed as
+:func:`sort_tile` so the single-kernel fused bucket pipeline
+(``kernels/fused_bucket.py``) runs the identical sort stage inside its
+own ``pallas_call``.
 """
 from __future__ import annotations
 
@@ -36,60 +33,46 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.formats import EMPTY
 from repro.kernels import _network as net
 
 
-def sort_tile(keys, vals, lens):
-    """Sort/combine/compress an (N, R) tile of chunks — pure jnp, usable
-    inside any Pallas kernel body.
+def sort_tile(k, v, R):
+    """Sort/combine/compress every R-wide chunk of a (T, 128) tile — pure
+    jnp, usable inside any Pallas kernel body.
 
-    keys: (N, R) int32, vals: (N, R) f32, lens: (N, 1) int32 valid
-    counts.  Returns (keys (N, R), vals (N, R), n (N,)) with the unique
-    sorted keys compressed to the front (EMPTY/0 beyond n), duplicate
-    values accumulated left-to-right — bit-identical to
-    ``ref.stream_sort_ref`` / ``merge_tree.sort_chunks_linear``."""
-    R = keys.shape[-1]
-    r = jax.lax.broadcasted_iota(jnp.int32, keys.shape, 1)
-    valid = r < lens
-    k = jnp.where(valid, keys, EMPTY)
-    v = jnp.where(valid, vals, 0.0)
-    # stable ascending sort (ties keep product order, like stable argsort)
-    k, _, v = net.bitonic_sort_stable(k, r, v)
+    k: int32 keys, EMPTY past each chunk's valid count; v: f32 values,
+    0 past it.  Returns (keys, vals) with each chunk's unique sorted keys
+    compressed to its front (EMPTY/0 behind), duplicate values
+    accumulated left-to-right — bit-identical to ``ref.stream_sort_ref``."""
+    lg = R.bit_length() - 1
+    k, v = net.sort_segments(k, v, lg)
     # linear run accumulation: acc[i] = left-to-right prefix of i's run;
     # adding the predecessor's finished prefix keeps the float association
     # linear, bit-identical to segment_sum's index-order adds
-    start = k != net.shift_right(k, 1, EMPTY)
-    s = jnp.where(start, r, 0)
-    d = 1
-    while d < R:  # Hillis-Steele max-scan: start index of each run
-        s = jnp.maximum(s, net.shift_right(s, d, 0))
-        d *= 2
+    r = net.seg_pos(k.shape, lg)
+    s = jnp.where(k != net.seg_shift(k, 1, lg, EMPTY), r, 0)
+    # Hillis-Steele max-scan: start index of each run
+    s = jax.lax.fori_loop(
+        0, lg, lambda e, s: jnp.maximum(
+            s, net.seg_shift(s, jnp.left_shift(1, e), lg, 0)), s)
     run_pos = r - s
-    acc = v
-    for d in range(1, R):
-        shifted = net.shift_right(acc, 1, 0.0)
-        acc = jnp.where(run_pos == d, shifted + v, acc)
+    acc = jax.lax.fori_loop(
+        1, R, lambda d, acc: jnp.where(
+            run_pos == d, net.seg_shift(acc, 1, lg, 0.0) + v, acc), v)
     # keep the run total (last element of each run), then compress
-    is_last = (k != net.shift_left(k, 1, EMPTY)) & (k != EMPTY)
-    k2 = jnp.where(is_last, k, EMPTY)
-    v2 = jnp.where(is_last, acc, 0.0)
-    return net.compress_onehot(k2, v2)
+    last = (k != net.seg_shift(k, -1, lg, EMPTY)) & (k != EMPTY)
+    return net.compact(jnp.where(last, k, EMPTY), jnp.where(last, acc, 0.0),
+                       lg)
 
 
-def _chunk_sort_kernel(keys_ref, vals_ref, lens_ref, ok_ref, ov_ref, ol_ref):
-    k3, v3, n = sort_tile(keys_ref[...], vals_ref[...].astype(jnp.float32),
-                          lens_ref[...])
-    ok_ref[...] = k3
-    ov_ref[...] = v3.astype(ov_ref.dtype)
-    ol_ref[...] = n[:, None]
+def _chunk_sort_kernel(keys_ref, vals_ref, ok_ref, ov_ref, *, R):
+    ok_ref[...], ov_ref[...] = sort_tile(keys_ref[...], vals_ref[...], R)
 
 
-@functools.partial(jax.jit, static_argnames=("block_n", "interpret"))
-def chunk_sort_pallas(keys, vals, lens, *, block_n: int = 8,
-                      interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def chunk_sort_pallas(keys, vals, lens, *, interpret: bool = False):
     """Sort/combine/compress all N key-value chunks in one kernel issue.
 
     keys: (N, R) int32; vals: (N, R) float; lens: (N,) int32.  R must be
@@ -99,27 +82,10 @@ def chunk_sort_pallas(keys, vals, lens, *, block_n: int = 8,
     assert R & (R - 1) == 0, "R must be a power of two"
     if N == 0:  # zero chunks: same empty outputs as the xla oracle
         return keys, vals, lens.astype(jnp.int32)
-    block_n = min(block_n, N)
-    pad = (-N) % block_n
-    if pad:
-        keys = jnp.pad(keys, ((0, pad), (0, 0)), constant_values=EMPTY)
-        vals = jnp.pad(vals, ((0, pad), (0, 0)))
-        lens = jnp.pad(lens, (0, pad))
-    Np = N + pad
-    lens2 = lens[:, None].astype(jnp.int32)
-    grid = (Np // block_n,)
-    kv_spec = pl.BlockSpec((block_n, R), lambda i: (i, 0))
-    len_spec = pl.BlockSpec((block_n, 1), lambda i: (i, 0))
-    ok, ov, ol = pl.pallas_call(
-        _chunk_sort_kernel,
-        grid=grid,
-        in_specs=[kv_spec, kv_spec, len_spec],
-        out_specs=[kv_spec, kv_spec, len_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((Np, R), jnp.int32),
-            jax.ShapeDtypeStruct((Np, R), vals.dtype),
-            jax.ShapeDtypeStruct((Np, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(keys, vals, lens2)
-    return ok[:N], ov[:N], ol[:N, 0]
+    k, v = net.mask_to_lens(keys, vals, lens)
+    ok, ov = net.stream_call(
+        functools.partial(_chunk_sort_kernel, R=R),
+        [(k, EMPTY), (v, 0.0)], [jnp.int32, jnp.float32],
+        width=R, interpret=interpret)
+    return ok, ov.astype(vals.dtype), jnp.sum(ok != EMPTY, axis=1,
+                                              dtype=jnp.int32)

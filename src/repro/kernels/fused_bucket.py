@@ -5,11 +5,12 @@ everything, then log2(C) merge rounds".  With the stage kernels issued
 separately, every round's partition buffers round-trip through HBM — the
 exact spill SparseZipper (and SpArch's hierarchical merge tree) exist to
 avoid.  This kernel runs the entire bucket pipeline inside a single
-``pallas_call``: one program holds its (BLOCK_S, L) stream tile in VMEM,
-chunk-sorts all BLOCK_S*C R-chunks (``chunk_sort.sort_tile`` — the same
-tile the standalone chunk-sort kernel runs), then folds the C sorted
-partitions through log2(C) unrolled rounds of
-``merge_partitions.merge_tile`` without the intermediate partitions ever
+``pallas_call``: one program holds a block of whole streams in VMEM
+(``_network``'s (T, 128) tile layout), chunk-sorts all their R-chunks
+(``chunk_sort.sort_tile`` — the same tile the standalone chunk-sort
+kernel runs), then folds the C sorted partitions through log2(C) rounds
+of ``merge_partitions.merge_tile`` — a ``fori_loop`` over the round, the
+pair width doubling each time — without the intermediate partitions ever
 leaving VMEM.
 
 Counters: the lock-step instruction accounting must match the host
@@ -23,11 +24,11 @@ of per-side ceil(rem/R)), so ``spgemm.fused_process_group`` consumes the
 result unchanged and rebuilds group-exact ``n_mssort``/``n_mszip``.
 
 Invariants: R is a power of two (bitonic sort width) and C = L/R is a
-power of two (balanced merge tree); keys beyond ``plens`` are EMPTY
-(they are masked again chunk-wise before sorting); valid keys < 2**31-1.
-Counter layout in the kernel outputs: round r's pairs occupy columns
-[C - C>>r, C - C>>(r+1)) of the (S, C-1) per-stream counter planes —
-round 0 first, C/2 + C/4 + ... + 1 = C-1 columns total.
+power of two (balanced merge tree); keys beyond ``plens`` may be garbage
+(the wrapper masks them to EMPTY); valid keys < 2**31-1.  Counter layout
+in the kernel outputs: four (S, L) planes (steps, zips, tail_a, tail_b);
+round r writes pair j of a stream at stream position j * 2**(r+1) * R
++ r, which no other round's pair uses.
 """
 from __future__ import annotations
 
@@ -35,64 +36,42 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.formats import EMPTY
+from repro.kernels import _network as net
 from repro.kernels.chunk_sort import sort_tile
 from repro.kernels.merge_partitions import advance_tile, merge_tile
 
 
-def _fused_bucket_kernel(keys_ref, vals_ref, plens_ref,
-                         ok_ref, ov_ref, ol_ref,
-                         st_ref, zp_ref, ta_ref, tb_ref, *,
-                         R: int, C: int, with_counters: bool):
-    Sb, L = keys_ref.shape
-    keys = keys_ref[...]
-    vals = vals_ref[...].astype(jnp.float32)
-    plens = plens_ref[...]  # (Sb, 1)
-    # per-chunk valid counts: chunk c of a stream holds
-    # clip(plen - c*R, 0, R) products
-    coff = jnp.arange(C, dtype=jnp.int32)[None, :] * R
-    clens = jnp.clip(plens - coff, 0, R).reshape(Sb * C, 1)
-    # sort stage: identical tile to the standalone chunk-sort kernel
-    pk, pv, pn = sort_tile(keys.reshape(Sb * C, R),
-                           vals.reshape(Sb * C, R), clens)
-    cnt_cols = [[], [], [], []]  # per-round (Sb, half) planes, in order
-    # merge tree: fold pairs of sorted partitions, chunks never leave VMEM
-    cur_c, W = C, R
-    while cur_c > 1:
-        half = cur_c // 2
-        k3 = pk.reshape(Sb, cur_c, W)
-        v3 = pv.reshape(Sb, cur_c, W)
-        n3 = pn.reshape(Sb, cur_c)
-        ka = k3[:, 0::2].reshape(Sb * half, W)
-        va = v3[:, 0::2].reshape(Sb * half, W)
-        la = n3[:, 0::2].reshape(Sb * half, 1)
-        kb = k3[:, 1::2].reshape(Sb * half, W)
-        vb = v3[:, 1::2].reshape(Sb * half, W)
-        lb = n3[:, 1::2].reshape(Sb * half, 1)
-        pk, pv, pn = merge_tile(ka, va, la, kb, vb, lb)
-        if with_counters:
-            round_cnts = advance_tile(ka, la, kb, lb, R)
-            for cols, c_r in zip(cnt_cols, round_cnts):
-                cols.append(c_r.reshape(Sb, half))
-        cur_c, W = half, 2 * W
-    ok_ref[...] = pk.reshape(Sb, L)
-    ov_ref[...] = pv.reshape(Sb, L).astype(ov_ref.dtype)
-    ol_ref[...] = pn.reshape(Sb, 1)
-    for ref, cols in zip((st_ref, zp_ref, ta_ref, tb_ref), cnt_cols):
-        if cols and sum(c.shape[1] for c in cols) == ref.shape[1]:
-            ref[...] = jnp.concatenate(cols, axis=1)
-        else:  # C == 1 (no rounds) or counters skipped: zero planes
-            ref[...] = jnp.zeros(ref.shape, jnp.int32)
+def _fused_bucket_kernel(k_ref, v_ref, ok_ref, ov_ref, *cnt_refs,
+                         R: int, C: int):
+    lg_r = R.bit_length() - 1
+    k, v = sort_tile(k_ref[...], v_ref[...], R)
+    planes = tuple(jnp.zeros(k.shape, jnp.int32) for _ in cnt_refs)
+
+    def merge_round(r, carry):
+        k, v, planes = carry
+        lg = lg_r + 1 + r  # pair width 2**(r+1) * R
+        if planes:
+            at = net.seg_pos(k.shape, lg) == r
+            planes = tuple(jnp.where(at, c, p) for c, p in
+                           zip(advance_tile(k, lg, R), planes))
+        k, v = merge_tile(k, v, lg)
+        return k, v, planes
+
+    k, v, planes = jax.lax.fori_loop(0, C.bit_length() - 1, merge_round,
+                                     (k, v, planes))
+    ok_ref[...] = k
+    ov_ref[...] = v
+    for ref, p in zip(cnt_refs, planes):
+        ref[...] = p
 
 
 @functools.partial(jax.jit, static_argnames=("R", "with_counters",
-                                             "detailed", "block_s",
-                                             "interpret"))
+                                             "detailed", "interpret"))
 def fused_bucket_pallas(keys, vals, plens, *, R: int,
                         with_counters: bool = True, detailed: bool = False,
-                        block_s: int = 8, interpret: bool = True):
+                        interpret: bool = False):
     """Sort + full zip-merge tree over one (S, L, R) work bucket in one
     kernel issue — same contract as ``core/stream.fused_sort_merge``.
 
@@ -112,58 +91,38 @@ def fused_bucket_pallas(keys, vals, plens, *, R: int,
     plens = plens.astype(jnp.int32)
     n_mssort = (-(-jnp.max(plens) // R)).astype(jnp.int32)
     sort_elems = jnp.sum(plens, dtype=jnp.int32)
-    # counter planes: round r at columns [C - (C >> r), ...), C-1 total
-    Cm1 = max(C - 1, 1)
-    block_s = min(block_s if not interpret else S, S)
-    pad_s = (-S) % block_s
-    if pad_s:
-        keys = jnp.pad(keys, ((0, pad_s), (0, 0)), constant_values=EMPTY)
-        vals = jnp.pad(vals, ((0, pad_s), (0, 0)))
-        plens = jnp.pad(plens, (0, pad_s))
-    Sp = S + pad_s
-    grid = (Sp // block_s,)
-    row_spec = pl.BlockSpec((block_s, L), lambda i: (i, 0))
-    one_spec = pl.BlockSpec((block_s, 1), lambda i: (i, 0))
-    cnt_spec = pl.BlockSpec((block_s, Cm1), lambda i: (i, 0))
-    kernel = functools.partial(_fused_bucket_kernel, R=R, C=C,
-                               with_counters=with_counters or detailed)
-    ok, ov, ol, st, zp, ta, tb = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[row_spec, row_spec, one_spec],
-        out_specs=[row_spec, row_spec, one_spec] + [cnt_spec] * 4,
-        out_shape=[
-            jax.ShapeDtypeStruct((Sp, L), jnp.int32),
-            jax.ShapeDtypeStruct((Sp, L), vals.dtype),
-            jax.ShapeDtypeStruct((Sp, 1), jnp.int32),
-        ] + [jax.ShapeDtypeStruct((Sp, Cm1), jnp.int32)] * 4,
-        interpret=interpret,
-    )(keys, vals, plens[:, None])
-    mk, mv, ml = ok[:S], ov[:S], ol[:S, 0]
-    # padded streams contribute zero steps/zips/tails, so reducing over
-    # the padded axis is safe; reduce exactly like zip_merge_tree reports
+    counted = (with_counters or detailed) and C > 1
+    k, v = net.mask_to_lens(keys, vals, plens)
+    res = net.stream_call(
+        functools.partial(_fused_bucket_kernel, R=R, C=C),
+        [(k, EMPTY), (v, 0.0)],
+        [jnp.int32, jnp.float32] + [jnp.int32] * (4 * counted),
+        width=L, interpret=interpret)
+    mk, mv = res[0], res[1].astype(vals.dtype)
+    ml = jnp.sum(mk != EMPTY, axis=1, dtype=jnp.int32)
+    # reduce per-(stream, pair) planes exactly like zip_merge_tree reports
+    # rounds: a pair's issue count is the max over its streams, zip_elems
+    # a sum, tails the max per side
     rounds = []
-    col, half = 0, C // 2
-    while half >= 1:
-        steps = jnp.max(st[:, col:col + half], axis=0)
-        ze = jnp.sum(zp[:, col:col + half], dtype=jnp.int32)
-        tails = jnp.stack([jnp.max(ta[:, col:col + half], axis=0),
-                           jnp.max(tb[:, col:col + half], axis=0)], axis=1)
-        rounds.append((steps, ze, tails))
-        col, half = col + half, half // 2
+    for r in range(C.bit_length() - 1 if counted else 0):
+        st, zp, ta, tb = (p.reshape(S, C >> (r + 1), -1)[:, :, r]
+                          for p in res[2:])
+        rounds.append((jnp.max(st, axis=0), jnp.sum(zp, dtype=jnp.int32),
+                       jnp.stack([jnp.max(ta, axis=0), jnp.max(tb, axis=0)],
+                                 axis=1)))
     if detailed:
+        if not counted:  # no merge rounds, or counters skipped
+            rounds = [(jnp.zeros((C >> (r + 1),), jnp.int32),
+                       jnp.zeros((), jnp.int32),
+                       jnp.zeros((C >> (r + 1), 2), jnp.int32))
+                      for r in range(C.bit_length() - 1)]
         return mk, mv, ml, tuple(rounds)
-    if with_counters:
-        n_zip = sum((jnp.sum(r[0], dtype=jnp.int32) for r in rounds),
-                    jnp.zeros((), jnp.int32))
-        zip_elems = sum((r[1] for r in rounds), jnp.zeros((), jnp.int32))
-        tail_sum = sum((jnp.sum(r[2], dtype=jnp.int32) for r in rounds),
-                       jnp.zeros((), jnp.int32))
-        chunk_loads = 2 * n_zip
-        chunk_stores = n_zip + tail_sum
-    else:
-        n_zip = zip_elems = chunk_loads = chunk_stores = \
-            jnp.zeros((), jnp.int32)
+    n_zip = sum((jnp.sum(r[0], dtype=jnp.int32) for r in rounds),
+                jnp.zeros((), jnp.int32))
+    zip_elems = sum((r[1] for r in rounds), jnp.zeros((), jnp.int32))
+    tail_sum = sum((jnp.sum(r[2], dtype=jnp.int32) for r in rounds),
+                   jnp.zeros((), jnp.int32))
     counters = jnp.stack([n_mssort, sort_elems, n_zip, zip_elems,
-                          n_mssort + chunk_loads, n_mssort + chunk_stores])
+                          n_mssort + 2 * n_zip,
+                          n_mssort + n_zip + tail_sum])
     return mk, mv, ml, counters

@@ -8,28 +8,29 @@ two padded sorted-unique partitions is one ``pallas_call``:
 
 payload
     Both inputs are already sorted, so the merge needs only the *cheap*
-    half of the sorting machinery: concatenating the ascending A side
-    with the flipped B side forms a bitonic sequence (EMPTY padding is
-    the peak), and ``_network.bitonic_merge_stable`` sorts it in log(W)
-    compare-exchange stages on (key, source-lane) pairs.  A-side lanes
-    are numbered below B-side lanes, so cross-side duplicate keys land
-    A-before-B deterministically.  Duplicates then accumulate with
-    ``combine_duplicates`` and ``compress_onehot`` packs the unique
-    survivors to the front.
+    half of the sorting machinery: with the A side in the lower half of a
+    stream's segment and the B side in the upper half,
+    ``_network.merge_segments`` merges them in log(W) compare-exchange
+    stages on (key, source-lane) pairs.  A-side lanes are numbered below
+    B-side lanes, so cross-side duplicate keys land A-before-B
+    deterministically.  Duplicates then accumulate with
+    ``combine_pairs`` and ``compact`` packs the unique survivors to the
+    front.
 
     Bit-identity with the XLA union merge: the inputs are sorted and
     duplicate-free per side, so a duplicate run has at most 2 elements
     and the accumulated value is the single IEEE add va + vb — the same
     add the union merge performs; all other values move through
-    where-selections and the exact one-hot compress, untouched.
+    where-selections and the compaction network, untouched.
 
 counters
     The SparseZipper chunk-advancement state machine (merge-bit cutoff =
     min of the two R-wide front maxima; consume every key <= cutoff)
     runs per stream inside the kernel as a vectorized
-    ``jax.lax.while_loop`` over read pointers — gather-free: chunk
-    fronts are masked window reductions, not dynamic slices.  Per-stream
-    step counts are returned and combined into per-*pair* issue counts
+    ``jax.lax.while_loop`` over read pointers — gather-free: the cutoff
+    is a segment min over the two fronts' last keys, the consumed counts
+    a segment sum, not dynamic slices.  Per-stream step counts are
+    returned and combined into per-*pair* issue counts
     outside (a pair's issue count is the max over its streams, zip_elems
     a plain sum, tails the max over streams of per-side ceil(rem/R)) —
     exactly ``merge_tree._advance_counters``'s accounting, which is
@@ -39,8 +40,9 @@ counters
 
 Invariants: each side's keys are ascending and duplicate-free within a
 row, EMPTY-padded past its ``lens`` (entries beyond lens are re-masked
-here, matching the oracle's lens-trust); the concatenated network width
-is a power of two (each side is padded to a shared pow2 width first).
+here, matching the oracle's lens-trust, so a side's valid count is its
+count of non-EMPTY keys); the concatenated network width is a power of
+two (each side is padded to a shared pow2 width first).
 """
 from __future__ import annotations
 
@@ -48,109 +50,89 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.formats import EMPTY
 from repro.kernels import _network as net
 from repro.kernels.merge_tree import MergeCounters
 
 
-def merge_tile(ka, va, la, kb, vb, lb):
-    """Merge two sorted-unique (N, W) tiles — pure jnp, usable inside any
-    Pallas kernel body.
+def merge_tile(k, v, lg):
+    """Merge the two sorted-unique halves of every 2**lg-wide segment
+    of a (T, 128) tile — pure jnp, usable inside any Pallas kernel body.
 
-    ka/kb: (N, Wa)/(N, Wb) int32 ascending keys; va/vb: f32 values;
-    la/lb: (N, 1) int32 valid counts.  Wa + Wb must be a power of two.
-    Returns (keys (N, Wa+Wb), vals, n (N,)) with the merged uniques
-    compressed to the front, cross-side duplicates accumulated —
+    Each half holds its valid keys ascending at its front, EMPTY/0
+    behind.  Returns (keys, vals) with the merged uniques compressed to
+    the segment's front, cross-side duplicates accumulated —
     bit-identical to ``merge_tree._union_merge``."""
-    Wa, Wb = ka.shape[-1], kb.shape[-1]
-    ia = jax.lax.broadcasted_iota(jnp.int32, ka.shape, ka.ndim - 1)
-    ib = jax.lax.broadcasted_iota(jnp.int32, kb.shape, kb.ndim - 1)
-    ka = jnp.where(ia < la, ka, EMPTY)
-    va = jnp.where(ia < la, va, 0.0)
-    kb = jnp.where(ib < lb, kb, EMPTY)
-    vb = jnp.where(ib < lb, vb, 0.0)
-    # ascending A ++ flipped B is bitonic (EMPTY is the peak); A lanes
-    # number below B lanes so equal keys order A-before-B
-    cat_k = jnp.concatenate([ka, jnp.flip(kb, axis=-1)], axis=-1)
-    cat_i = jnp.concatenate([ia, jnp.flip(ib + Wa, axis=-1)], axis=-1)
-    cat_v = jnp.concatenate([va, jnp.flip(vb, axis=-1)], axis=-1)
-    k, _, v = net.bitonic_merge_stable(cat_k, cat_i, cat_v)
-    # per-side-unique inputs => duplicate runs have <= 2 elements, so the
-    # log-step scan reduces to the single add va + vb
-    k, v = net.combine_duplicates(k, v)
-    return net.compress_onehot(k, v)
+    k, v = net.merge_segments(k, v, lg)
+    # per-side-unique inputs => a key occurs at most twice, so the
+    # accumulation is the single add va + vb
+    k, v = net.combine_pairs(k, v, lg)
+    return net.compact(k, v, lg)
 
 
-def advance_tile(ka, la, kb, lb, R: int):
+def advance_tile(k, lg, R: int):
     """Per-stream chunk-advancement state machine — pure jnp while_loop,
     usable inside any Pallas kernel body.
 
-    ka/kb: (N, *) int32 ascending EMPTY-padded keys; la/lb: (N, 1) valid
-    counts; R: modelled mszip chunk width.  Returns per-stream (N, 1)
-    int32 (steps, zip_elems, tail_a, tail_b): lock-step advancement steps
-    while both sides are live, tuples presented through the fronts, and
-    leftover copy-through chunk counts per side."""
-    ia = jax.lax.broadcasted_iota(jnp.int32, ka.shape, ka.ndim - 1)
-    ib = jax.lax.broadcasted_iota(jnp.int32, kb.shape, kb.ndim - 1)
-    z = jnp.zeros(la.shape, jnp.int32)
+    k: (T, 128) int32 keys laid out as in :func:`merge_tile` (A in the
+    lower half of each 2**lg-wide segment, B in the upper, each
+    ascending and compacted); R: modelled mszip chunk width.  Returns
+    int32 (steps, zip_elems, tail_a, tail_b), each broadcast over its
+    segment: lock-step advancement steps while both sides are live,
+    tuples presented through the fronts, and leftover copy-through chunk
+    counts per side."""
+    pos = net.seg_pos(k.shape, lg)
+    idx = net.seg_pos(k.shape, lg - 1)  # position within its side
+    is_a = pos == idx
+    valid = (k != EMPTY).astype(jnp.int32)
+    la = net.seg_allreduce(jnp.where(is_a, valid, 0), lg, jnp.add)
+    lb = net.seg_allreduce(jnp.where(is_a, 0, valid), lg, jnp.add)
+    z = jnp.zeros(k.shape, jnp.int32)
 
     def cond(state):
         pa, pb, _, _ = state
-        return jnp.any((pa < la) & (pb < lb))
+        return jnp.max(((pa < la) & (pb < lb)).astype(jnp.int32)) > 0
 
     def body(state):
         pa, pb, steps, zips = state
         both = (pa < la) & (pb < lb)
-        ea = jnp.where(both, la, 0)  # effective lens: inactive => empty
-        eb = jnp.where(both, lb, 0)
-        ma = (ia >= pa) & (ia < pa + R) & (ia < ea)
-        mb = (ib >= pb) & (ib < pb + R) & (ib < eb)
-        # merge-bit cutoff: max valid key per front (-1 when empty)
-        max_a = jnp.max(jnp.where(ma, ka, -1), axis=-1, keepdims=True)
-        max_b = jnp.max(jnp.where(mb, kb, -1), axis=-1, keepdims=True)
-        cutoff = jnp.minimum(max_a, max_b)
-        ca = jnp.sum(ma & (ka <= cutoff), axis=-1, dtype=jnp.int32,
-                     keepdims=True)
-        cb = jnp.sum(mb & (kb <= cutoff), axis=-1, dtype=jnp.int32,
-                     keepdims=True)
-        fa_n = jnp.sum(ma, axis=-1, dtype=jnp.int32, keepdims=True)
-        fb_n = jnp.sum(mb, axis=-1, dtype=jnp.int32, keepdims=True)
-        return (pa + ca, pb + cb, steps + both.astype(jnp.int32),
-                zips + fa_n + fb_n)
+        # fronts: [p, min(p + R, len)) per side, empty unless both live
+        p = jnp.where(is_a, pa, pb)
+        e = jnp.where(both, jnp.minimum(p + R, jnp.where(is_a, la, lb)), 0)
+        front = (idx >= p) & (idx < e)
+        # merge-bit cutoff: the smaller of the two fronts' last keys
+        # (fronts are sorted, so a front's max is its last key)
+        cutoff = net.seg_allreduce(
+            jnp.where(front & (idx == e - 1), k, EMPTY), lg, jnp.minimum)
+        took = jnp.where(front & (k <= cutoff),
+                         jnp.where(is_a, 1, 1 << 16), 0)
+        took = net.seg_allreduce(took, lg, jnp.add)
+        fa = jnp.where(both, jnp.minimum(la - pa, R), 0)
+        fb = jnp.where(both, jnp.minimum(lb - pb, R), 0)
+        return (pa + (took & 0xFFFF), pb + (took >> 16),
+                steps + both.astype(jnp.int32), zips + fa + fb)
 
     pa, pb, steps, zips = jax.lax.while_loop(cond, body, (z, z, z, z))
-    tail_a = -(-jnp.maximum(la - pa, 0) // R)
-    tail_b = -(-jnp.maximum(lb - pb, 0) // R)
-    return steps, zips, tail_a, tail_b
+    return (steps, zips, _ceil_div(jnp.maximum(la - pa, 0), R),
+            _ceil_div(jnp.maximum(lb - pb, 0), R))
 
 
-def _merge_partitions_kernel(ka_ref, va_ref, la_ref, kb_ref, vb_ref, lb_ref,
-                             ok_ref, ov_ref, ol_ref, st_ref, zp_ref,
-                             ta_ref, tb_ref, *, R: int, with_counters: bool):
-    ka = ka_ref[...]
-    va = va_ref[...].astype(jnp.float32)
-    la = la_ref[...]
-    kb = kb_ref[...]
-    vb = vb_ref[...].astype(jnp.float32)
-    lb = lb_ref[...]
-    mk, mv, mn = merge_tile(ka, va, la, kb, vb, lb)
-    ok_ref[...] = mk
-    ov_ref[...] = mv.astype(ov_ref.dtype)
-    ol_ref[...] = mn[:, None]
-    if with_counters:
-        steps, zips, ta, tb = advance_tile(ka, la, kb, lb, R)
-        st_ref[...] = steps
-        zp_ref[...] = zips
-        ta_ref[...] = ta
-        tb_ref[...] = tb
-    else:
-        z = jnp.zeros(la.shape, jnp.int32)
-        st_ref[...] = z
-        zp_ref[...] = z
-        ta_ref[...] = z
-        tb_ref[...] = z
+def _ceil_div(x, R):
+    return (x + (R - 1)) >> (R.bit_length() - 1)
+
+
+def _merge_partitions_kernel(k_ref, v_ref, ok_ref, ov_ref, st_ref, zp_ref,
+                             ta_ref, tb_ref, *, R: int, lg: int):
+    k = k_ref[...]
+    ok_ref[...], ov_ref[...] = merge_tile(k, v_ref[...], lg)
+    for ref, c in zip((st_ref, zp_ref, ta_ref, tb_ref),
+                      advance_tile(k, lg, R)):
+        ref[...] = c
+
+
+def _merge_payload_kernel(k_ref, v_ref, ok_ref, ov_ref, *, lg: int):
+    ok_ref[...], ov_ref[...] = merge_tile(k_ref[...], v_ref[...], lg)
 
 
 def _next_pow2(n: int) -> int:
@@ -158,19 +140,19 @@ def _next_pow2(n: int) -> int:
 
 
 @functools.partial(jax.jit, static_argnames=("R", "pair_streams",
-                                             "with_counters", "block_n",
-                                             "interpret"))
+                                             "with_counters", "interpret"))
 def merge_partitions_pallas(ka, va, la, kb, vb, lb, *, R: int,
                             pair_streams: int | None = None,
                             with_counters: bool = True,
-                            block_n: int = 8, interpret: bool = True):
+                            interpret: bool = False):
     """Fully merge two padded sorted-unique partitions per stream in one
     ``pallas_call`` — same contract as ``merge_tree.merge_partitions``.
 
     ka/kb: (N, La)/(N, Lb) int32 keys (EMPTY padded); va/vb: values;
     la/lb: (N,) valid lengths.  R: chunk width of the modelled mszip
-    issue; ``pair_streams``: lock-step group size S for the instruction
-    accounting (rows [p*S, (p+1)*S) form pair p; default: one pair).
+    issue (a power of two); ``pair_streams``: lock-step group size S for
+    the instruction accounting (rows [p*S, (p+1)*S) form pair p; default:
+    one pair).
 
     Returns (keys (N, La+Lb), vals, lens, MergeCounters), bit-identical
     to the XLA backend including the exact counter values.
@@ -179,59 +161,45 @@ def merge_partitions_pallas(ka, va, la, kb, vb, lb, *, R: int,
     Lb = kb.shape[1]
     Lo = La + Lb
     S = pair_streams or N
-    la = la.astype(jnp.int32)
-    lb = lb.astype(jnp.int32)
     zero = jnp.zeros((), jnp.int32)
     if N == 0 or Lo == 0:
         return (jnp.full((N, Lo), EMPTY, jnp.int32),
                 jnp.zeros((N, Lo), va.dtype), jnp.zeros((N,), jnp.int32),
                 MergeCounters(zero, zero, zero, zero))
     assert N % S == 0, f"pair_streams {S} must divide stream count {N}"
-    # pad each side to a shared pow2 width so the concatenated bitonic
-    # network width 2*Wm is a power of two even for ragged La/Lb
+    assert R & (R - 1) == 0, "R must be a power of two"
+    # pad each side to a shared pow2 width Wm: A fills the lower half of
+    # a 2*Wm-wide segment, B the upper
     Wm = _next_pow2(max(La, Lb, 1))
-    ka = jnp.pad(ka, ((0, 0), (0, Wm - La)), constant_values=EMPTY)
-    va = jnp.pad(va, ((0, 0), (0, Wm - La)))
-    kb = jnp.pad(kb, ((0, 0), (0, Wm - Lb)), constant_values=EMPTY)
-    vb = jnp.pad(vb, ((0, 0), (0, Wm - Lb)))
-    block_n = min(block_n if not interpret else N, N)
-    pad_n = (-N) % block_n
-    if pad_n:
-        ka = jnp.pad(ka, ((0, pad_n), (0, 0)), constant_values=EMPTY)
-        va = jnp.pad(va, ((0, pad_n), (0, 0)))
-        kb = jnp.pad(kb, ((0, pad_n), (0, 0)), constant_values=EMPTY)
-        vb = jnp.pad(vb, ((0, pad_n), (0, 0)))
-        la = jnp.pad(la, (0, pad_n))
-        lb = jnp.pad(lb, (0, pad_n))
-    Np = N + pad_n
-    W = 2 * Wm
-    grid = (Np // block_n,)
-    kv_spec = pl.BlockSpec((block_n, Wm), lambda i: (i, 0))
-    out_spec = pl.BlockSpec((block_n, W), lambda i: (i, 0))
-    one_spec = pl.BlockSpec((block_n, 1), lambda i: (i, 0))
-    kernel = functools.partial(_merge_partitions_kernel, R=R,
-                               with_counters=with_counters)
-    ok, ov, ol, st, zp, ta, tb = pl.pallas_call(
-        kernel,
-        grid=grid,
-        in_specs=[kv_spec, kv_spec, one_spec, kv_spec, kv_spec, one_spec],
-        out_specs=[out_spec, out_spec] + [one_spec] * 5,
-        out_shape=[
-            jax.ShapeDtypeStruct((Np, W), jnp.int32),
-            jax.ShapeDtypeStruct((Np, W), va.dtype),
-        ] + [jax.ShapeDtypeStruct((Np, 1), jnp.int32)] * 5,
-        interpret=interpret,
-    )(ka, va, la[:, None], kb, vb, lb[:, None])
-    ko, vo, lo = ok[:N, :Lo], ov[:N, :Lo], ol[:N, 0]
+    dtype = va.dtype
+    ka, va = net.mask_to_lens(ka, va, la.astype(jnp.int32))
+    kb, vb = net.mask_to_lens(kb, vb, lb.astype(jnp.int32))
+    k = jnp.concatenate([
+        jnp.pad(ka, ((0, 0), (0, Wm - La)), constant_values=EMPTY),
+        jnp.pad(kb, ((0, 0), (0, Wm - Lb)), constant_values=EMPTY)], axis=1)
+    v = jnp.concatenate([jnp.pad(va, ((0, 0), (0, Wm - La))),
+                         jnp.pad(vb, ((0, 0), (0, Wm - Lb)))], axis=1)
+    seg = 2 * Wm
     if with_counters:
-        P = N // S
-        steps_p = jnp.max(st[:N, 0].reshape(P, S), axis=1)
-        n_zip = jnp.sum(steps_p, dtype=jnp.int32)
-        zip_elems = jnp.sum(zp[:N, 0], dtype=jnp.int32)
-        tails = (jnp.max(ta[:N, 0].reshape(P, S), axis=1)
-                 + jnp.max(tb[:N, 0].reshape(P, S), axis=1))
-        cnt = MergeCounters(n_zip, zip_elems, 2 * n_zip,
-                            n_zip + jnp.sum(tails, dtype=jnp.int32))
+        kernel = functools.partial(_merge_partitions_kernel, R=R,
+                                   lg=seg.bit_length() - 1)
+        outs = [jnp.int32, jnp.float32] + [jnp.int32] * 4
     else:
-        cnt = MergeCounters(zero, zero, zero, zero)
+        kernel = functools.partial(_merge_payload_kernel,
+                                   lg=seg.bit_length() - 1)
+        outs = [jnp.int32, jnp.float32]
+    res = net.stream_call(kernel, [(k, EMPTY), (v, 0.0)], outs, width=seg,
+                          interpret=interpret)
+    ko, vo = res[0][:, :Lo], res[1][:, :Lo].astype(dtype)
+    lo = jnp.sum(ko != EMPTY, axis=1, dtype=jnp.int32)
+    if not with_counters:
+        return ko, vo, lo, MergeCounters(zero, zero, zero, zero)
+    st, zp, ta, tb = (c[:, 0] for c in res[2:])
+    P = N // S
+    steps_p = jnp.max(st.reshape(P, S), axis=1)
+    n_zip = jnp.sum(steps_p, dtype=jnp.int32)
+    tails = (jnp.max(ta.reshape(P, S), axis=1)
+             + jnp.max(tb.reshape(P, S), axis=1))
+    cnt = MergeCounters(n_zip, jnp.sum(zp, dtype=jnp.int32), 2 * n_zip,
+                        n_zip + jnp.sum(tails, dtype=jnp.int32))
     return ko, vo, lo, cnt

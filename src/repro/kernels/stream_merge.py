@@ -12,7 +12,9 @@ with the paper's data-dependent advancement semantics:
 
 Because both inputs are sorted, the merge needs only the log(2R)-stage
 bitonic *merge* network — the same asymptotic win the systolic zip pass
-gets over a full sort.
+gets over a full sort.  Each stream is a 2R-wide segment of
+``_network``'s tile layout: side A in the lower half, side B in the
+upper.
 """
 from __future__ import annotations
 
@@ -20,94 +22,44 @@ import functools
 
 import jax
 import jax.numpy as jnp
-from jax.experimental import pallas as pl
 
 from repro.core.formats import EMPTY
 from repro.kernels import _network as net
 
 
-def _stream_merge_kernel(ka_ref, va_ref, la_ref, kb_ref, vb_ref, lb_ref,
-                         klo_ref, vlo_ref, khi_ref, vhi_ref,
-                         ca_ref, cb_ref, ol_ref):
-    ka, va = ka_ref[...], va_ref[...].astype(jnp.float32)
-    kb, vb = kb_ref[...], vb_ref[...].astype(jnp.float32)
-    la, lb = la_ref[...], lb_ref[...]
-    r = jax.lax.broadcasted_iota(jnp.int32, ka.shape, 1)
-    va_ok = r < la
-    vb_ok = r < lb
-    ka = jnp.where(va_ok, ka, EMPTY)
-    kb = jnp.where(vb_ok, kb, EMPTY)
-    va = jnp.where(va_ok, va, 0.0)
-    vb = jnp.where(vb_ok, vb, 0.0)
+def _stream_merge_kernel(k_ref, v_ref, ok_ref, ov_ref, took_ref, *, lg):
+    k, v = k_ref[...], v_ref[...]
+    is_a = net.seg_pos(k.shape, lg) == net.seg_pos(k.shape, lg - 1)
     # merge-bit cutoff: max valid key per side (-1 when empty)
-    max_a = jnp.max(jnp.where(ka != EMPTY, ka, -1), axis=-1, keepdims=True)
-    max_b = jnp.max(jnp.where(kb != EMPTY, kb, -1), axis=-1, keepdims=True)
-    cutoff = jnp.minimum(max_a, max_b)
-    ma = (ka != EMPTY) & (ka <= cutoff)
-    mb = (kb != EMPTY) & (kb <= cutoff)
-    ca_ref[...] = jnp.sum(ma, axis=-1, dtype=jnp.int32)[:, None]
-    cb_ref[...] = jnp.sum(mb, axis=-1, dtype=jnp.int32)[:, None]
-    # bitonic concat: ascending a ++ reversed b (descending)
-    cat_k = jnp.concatenate(
-        [jnp.where(ma, ka, EMPTY), jnp.flip(jnp.where(mb, kb, EMPTY), -1)], -1)
-    cat_v = jnp.concatenate(
-        [jnp.where(ma, va, 0.0), jnp.flip(jnp.where(mb, vb, 0.0), -1)], -1)
-    # zip pass: single bitonic merge network
-    cat_k, cat_v = net.bitonic_merge(cat_k, cat_v)
-    cat_k, cat_v = net.combine_duplicates(cat_k, cat_v)
-    # compress pass
-    cat_k, cat_v, n = net.compress_onehot(cat_k, cat_v)
-    R = ka.shape[-1]
-    klo_ref[...] = cat_k[:, :R]
-    khi_ref[...] = cat_k[:, R:]
-    vlo_ref[...] = cat_v[:, :R].astype(vlo_ref.dtype)
-    vhi_ref[...] = cat_v[:, R:].astype(vhi_ref.dtype)
-    ol_ref[...] = n[:, None]
+    max_a = net.seg_allreduce(jnp.where(is_a & (k != EMPTY), k, -1), lg,
+                              jnp.maximum)
+    max_b = net.seg_allreduce(jnp.where(~is_a & (k != EMPTY), k, -1), lg,
+                              jnp.maximum)
+    m = (k != EMPTY) & (k <= jnp.minimum(max_a, max_b))
+    # consumed counts, side A in the low 16 bits, side B above
+    took_ref[...] = net.seg_allreduce(
+        jnp.where(m, jnp.where(is_a, 1, 1 << 16), 0), lg, jnp.add)
+    k, v = net.merge_segments(jnp.where(m, k, EMPTY), jnp.where(m, v, 0.0),
+                              lg)
+    k, v = net.combine_pairs(k, v, lg)
+    ok_ref[...], ov_ref[...] = net.compact(k, v, lg)
 
 
-@functools.partial(jax.jit, static_argnames=("block_s", "interpret"))
-def stream_merge_pallas(ka, va, la, kb, vb, lb, *, block_s: int = 8,
-                        interpret: bool = True):
+@functools.partial(jax.jit, static_argnames=("interpret",))
+def stream_merge_pallas(ka, va, la, kb, vb, lb, *, interpret: bool = False):
     """All chunk args (S, R); lens (S,). Returns
     (k_lo, v_lo, k_hi, v_hi, consumed_a, consumed_b, out_lens)."""
     S, R = ka.shape
     assert R & (R - 1) == 0, "R must be a power of two"
-    block_s = min(block_s, S)
-    pad = (-S) % block_s
-    if pad:
-        def pk(x):
-            return jnp.pad(x, ((0, pad), (0, 0)), constant_values=EMPTY)
-
-        def pv(x):
-            return jnp.pad(x, ((0, pad), (0, 0)))
-
-        def pl_(x):
-            return jnp.pad(x, (0, pad))
-        ka, va, kb, vb = pk(ka), pv(va), pk(kb), pv(vb)
-        la, lb = pl_(la), pl_(lb)
-    Sp = S + pad
-    la2 = la[:, None].astype(jnp.int32)
-    lb2 = lb[:, None].astype(jnp.int32)
-    grid = (Sp // block_s,)
-    kv_spec = pl.BlockSpec((block_s, R), lambda i: (i, 0))
-    len_spec = pl.BlockSpec((block_s, 1), lambda i: (i, 0))
-    outs = pl.pallas_call(
-        _stream_merge_kernel,
-        grid=grid,
-        in_specs=[kv_spec, kv_spec, len_spec, kv_spec, kv_spec, len_spec],
-        out_specs=[kv_spec, kv_spec, kv_spec, kv_spec,
-                   len_spec, len_spec, len_spec],
-        out_shape=[
-            jax.ShapeDtypeStruct((Sp, R), jnp.int32),
-            jax.ShapeDtypeStruct((Sp, R), va.dtype),
-            jax.ShapeDtypeStruct((Sp, R), jnp.int32),
-            jax.ShapeDtypeStruct((Sp, R), va.dtype),
-            jax.ShapeDtypeStruct((Sp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((Sp, 1), jnp.int32),
-            jax.ShapeDtypeStruct((Sp, 1), jnp.int32),
-        ],
-        interpret=interpret,
-    )(ka, va, la2, kb, vb, lb2)
-    klo, vlo, khi, vhi, ca, cb, ol = outs
-    return (klo[:S], vlo[:S], khi[:S], vhi[:S],
-            ca[:S, 0], cb[:S, 0], ol[:S, 0])
+    ka, fa = net.mask_to_lens(ka, va, la.astype(jnp.int32))
+    kb, fb = net.mask_to_lens(kb, vb, lb.astype(jnp.int32))
+    ok, ov, took = net.stream_call(
+        functools.partial(_stream_merge_kernel, lg=R.bit_length()),
+        [(jnp.concatenate([ka, kb], axis=1), EMPTY),
+         (jnp.concatenate([fa, fb], axis=1), 0.0)],
+        [jnp.int32, jnp.float32, jnp.int32], width=2 * R,
+        interpret=interpret)
+    ov = ov.astype(va.dtype)
+    return (ok[:, :R], ov[:, :R], ok[:, R:], ov[:, R:],
+            took[:, 0] & 0xFFFF, took[:, 0] >> 16,
+            jnp.sum(ok != EMPTY, axis=1, dtype=jnp.int32))

@@ -50,6 +50,7 @@ import numpy as np
 from repro.core import dispatch as dp
 from repro.core.formats import random_sparse
 from repro.distributed.spgemm_shard import kill_worker_spec
+from repro.launch.compile_cache import enable_compile_cache
 from repro.runtime import faultinject as fi
 from repro.serving.spgemm_service import SpGemmService
 
@@ -129,6 +130,7 @@ def main() -> None:
                          "the first request, and keep warming buckets "
                          "predicted from the admission stream")
     args = ap.parse_args()
+    enable_compile_cache()
 
     cache = dp.AutotuneCache(args.cache or os.path.join(
         tempfile.mkdtemp(prefix="serve_spgemm_"), "autotune.json"))

@@ -202,13 +202,12 @@ def _shardmap_moe(p, x, cfg):
             aux = jax.lax.pmean(aux, a)
         return y.reshape(bl, sl, D), aux
 
-    from jax.experimental.shard_map import shard_map
     x_spec = P(ba if (ba and B % max(1, shd.data_axis_size()) == 0) else None,
                "model" if seq_shard else None, None)
-    y, aux = shard_map(
+    y, aux = jax.shard_map(
         body, mesh=mesh,
         in_specs=(P(None, None), w_spec, w_spec, w2_spec, x_spec),
         out_specs=(x_spec, P()),
-        check_rep=False,
+        check_vma=False,
     )(p["router"]["w"], we["w1"], we["w3"], we["w2"], x)
     return y, aux

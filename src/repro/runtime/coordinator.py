@@ -66,6 +66,7 @@ from __future__ import annotations
 
 import collections
 import dataclasses
+import glob
 import hashlib
 import multiprocessing as mp
 import multiprocessing.connection as mpc
@@ -76,6 +77,46 @@ import time
 from typing import Any, Optional, Sequence, Union
 
 from repro.runtime import faultinject as fi
+
+
+class ChipContention(RuntimeError):
+    """Worker processes would have to share a TPU chip.  A chip belongs
+    to one process: a second one that opens it fails or hangs."""
+
+
+def _host_tpu_chips() -> int:
+    """TPU chips this host exposes to libtpu, read from the device files
+    without initialising JAX (0 when JAX is held to other platforms)."""
+    import jax
+    platforms = jax.config.jax_platforms or ""
+    if platforms and "tpu" not in platforms.split(","):
+        return 0
+    return (len(glob.glob("/dev/accel[0-9]*"))
+            or len(glob.glob("/dev/vfio/[0-9]*")))
+
+
+def check_one_process_per_chip(n_workers: int) -> None:
+    """Fail fast where ``n_workers`` spawned workers could not own the
+    chips they open: when this process has already initialised JAX on a
+    TPU (it holds the chips), or when the host has TPU chips at all —
+    every worker opens all visible chips, and the serving process builds
+    its CSR operands with JAX too, so any pool would put more than one
+    process on a chip."""
+    import jax
+    from jax._src import xla_bridge
+    if xla_bridge.backends_are_initialized() and any(
+            d.platform == "tpu" for d in jax.devices()):
+        raise ChipContention(
+            "this process has initialised JAX on the TPU and holds its "
+            "chips, so worker processes could not open them; serve "
+            "in-process (SpGemmService without a coordinator)")
+    chips = _host_tpu_chips()
+    if chips:
+        raise ChipContention(
+            f"{n_workers} worker process(es) plus this serving process "
+            f"would all open the {chips} TPU chip(s) of this host, and a "
+            f"chip belongs to one process; serve in-process "
+            f"(SpGemmService without a coordinator) on a TPU host")
 
 
 class PoolLost(RuntimeError):
@@ -318,6 +359,10 @@ class ProcessCoordinator:
     n_workers:           pool size.
     n_lanes:             device-lane space partitioned over the pool
                          (default: the parent's visible device count).
+
+    A chip belongs to one process: construction raises
+    :class:`ChipContention` (see :func:`check_one_process_per_chip`)
+    instead of spawning workers that would contend for a TPU.
     cache_path:          shared autotune/quarantine cache file; every
                          worker opens its own ``AutotuneCache`` on it
                          (push-on-quarantine / pull-on-plan-miss make
@@ -359,6 +404,7 @@ class ProcessCoordinator:
                  start_timeout_s: float = 120.0):
         if n_workers < 1:
             raise ValueError(f"n_workers must be >= 1, got {n_workers}")
+        check_one_process_per_chip(n_workers)
         if n_lanes is None:
             import jax
             n_lanes = len(jax.devices())

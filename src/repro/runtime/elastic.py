@@ -29,15 +29,15 @@ def remesh(n_devices: int, *, multi_pod: bool = False):
     """Build the largest (data, model) mesh for the available devices,
     holding the model axis fixed and scaling the data axis — the policy a
     resize controller would use when pods join/leave."""
-    from repro.launch.mesh import make_production_mesh  # lazy
+    from repro.launch.mesh import make_mesh, make_production_mesh  # lazy
     try:
         return make_production_mesh(multi_pod=multi_pod)
     except Exception:
         devs = jax.devices()[:n_devices]
         model = min(16, len(devs))
         data = len(devs) // model
-        return jax.make_mesh((data, model), ("data", "model"),
-                             devices=devs[: data * model])
+        return make_mesh((data, model), ("data", "model"),
+                         devices=devs[: data * model])
 
 
 def remesh_lanes(n_lanes: int, n_workers: int) -> list[range]:

@@ -22,6 +22,7 @@ sys.path.insert(0, {repr(SRC)})
 import jax, numpy as np, jax.numpy as jnp, dataclasses
 from repro.configs import base as cb
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 {body}
 """
     r = subprocess.run([sys.executable, "-c", script], capture_output=True,
@@ -37,7 +38,7 @@ cfg = dataclasses.replace(cb.get_smoke_config("arctic_480b"),
                           moe_dispatch="zipper", num_experts=8,
                           capacity_factor=8.0)
 key = jax.random.PRNGKey(0)
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 x = jax.random.normal(key, (4, 16, cfg.d_model), jnp.float32)
 p = moe_mod.moe_init(key, cfg, jnp.float32)
 y_ref, _ = moe_mod.moe_block(p, x, cfg, dispatch="einsum")
@@ -73,7 +74,7 @@ batch["labels"] = batch["tokens"]
 state0 = st.init_train_state(cfg, opt_cfg, key)
 _, m0 = jax.jit(st.make_train_step(cfg, opt_cfg))(state0, batch)
 # 2x4 mesh
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with shd.use_mesh(mesh):
     shapes = st.train_state_shapes(cfg, opt_cfg)
     sh = st.state_shardings(cfg, shapes)
@@ -93,7 +94,7 @@ def test_param_sharding_rules():
 import functools
 from repro.models import model as M
 cfg = cb.get_smoke_config("deepseek_v2_236b")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with shd.use_mesh(mesh):
     shapes = jax.eval_shape(functools.partial(M.init_params, cfg),
                             jax.ShapeDtypeStruct((2,), jnp.uint32))
@@ -121,7 +122,7 @@ toks = jax.random.randint(key, (4, 16), 0, cfg.vocab_size)
 cache = M.init_cache(cfg, 4, 32)
 lg0, c0 = M.prefill(params, cfg, toks, cache)
 d0, _ = M.decode_step(params, cfg, toks[:, :1], c0, jnp.int32(16))
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with shd.use_mesh(mesh):
     cache = M.init_cache(cfg, 4, 32)
     c_sh = st.cache_shardings(jax.eval_shape(lambda: cache))

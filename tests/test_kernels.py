@@ -5,7 +5,7 @@ import pytest
 
 from repro.core.formats import EMPTY
 from repro.kernels import ops, ref
-from repro.kernels.stream_sort import stream_sort_pallas
+from repro.kernels.chunk_sort import chunk_sort_pallas
 from repro.kernels.stream_merge import stream_merge_pallas
 
 RNG = np.random.default_rng(42)
@@ -41,7 +41,7 @@ def test_stream_sort_matches_ref(R, S, vdtype):
     vals = vals.astype(vdtype)
     args = (jnp.asarray(keys), jnp.asarray(vals), jnp.asarray(lens))
     rk, rv, rl = ref.stream_sort_ref(*args)
-    pk, pv, plen = stream_sort_pallas(*args, interpret=True)
+    pk, pv, plen = chunk_sort_pallas(*args, interpret=True)
     np.testing.assert_array_equal(np.asarray(pk), np.asarray(rk))
     np.testing.assert_allclose(np.asarray(pv, np.float32),
                                np.asarray(rv, np.float32),

@@ -249,3 +249,30 @@ def test_pool_lost_falls_back_to_local_ladder(tmp_path):
         assert pool.alive_count == 0  # the pool really is gone
     assert len(service.completed) == 8 and not service.dead_letters
     assert service.stats()["availability"] == 1.0
+
+
+# ---------------------------------------------------------------------------
+# one process per chip: the pool refuses to spawn where workers would
+# contend for a TPU, instead of hanging
+# ---------------------------------------------------------------------------
+
+class _FakeTpu:
+    platform = "tpu"
+
+
+def test_coordinator_refuses_when_parent_holds_tpu(monkeypatch):
+    from jax._src import xla_bridge
+    monkeypatch.setattr(xla_bridge, "backends_are_initialized", lambda: True)
+    import jax
+    monkeypatch.setattr(jax, "devices", lambda *a: [_FakeTpu()])
+    with pytest.raises(coord.ChipContention, match="holds its chips"):
+        coord.ProcessCoordinator(1)
+
+
+@pytest.mark.parametrize("n_workers", [1, 4])
+def test_coordinator_refuses_on_tpu_host(monkeypatch, n_workers):
+    monkeypatch.setattr(coord, "_host_tpu_chips", lambda: 4)
+    with pytest.raises(coord.ChipContention,
+                       match=f"{n_workers} worker process"):
+        coord.ProcessCoordinator(n_workers)
+

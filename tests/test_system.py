@@ -101,10 +101,11 @@ sys.path.insert(0, {repr(os.path.join(os.path.dirname(__file__), '..', 'src'))})
 from repro.checkpoint import ckpt
 from repro.configs import base as cb
 from repro.distributed import sharding as shd
+from repro.launch.mesh import make_mesh
 from repro.models import model as M
 import functools
 cfg = cb.get_smoke_config("tinyllama_1_1b")
-mesh = jax.make_mesh((2, 4), ("data", "model"))
+mesh = make_mesh((2, 4), ("data", "model"))
 with shd.use_mesh(mesh):
     shapes = jax.eval_shape(functools.partial(M.init_params, cfg),
                             jax.ShapeDtypeStruct((2,), jax.numpy.uint32))
