@@ -54,6 +54,7 @@ import jax.numpy as jnp
 # alias (not ``from repro.core import spgemm``) is the stable way to
 # reach the module once the package is initialized.
 from repro.core import spgemm as sg
+from repro.core import trace
 from repro.core.formats import (BatchedCSR, CSR, batch_csr, csr_from_coo,
                                 csr_to_numpy, validate_operands)
 from repro.kernels import backend as kb
@@ -1219,7 +1220,9 @@ def execute(p: ExecutionPlan, A: CSR, B: CSR, *,
             f"got {A.shape} @ {B.shape}")
     spec = get_engine(p.engine)
     fi.fire("dispatch.execute", engine=p.engine, backend=p.backend)
-    out = spec.fn(A, B, **p.kwargs_dict)
+    with trace.span(trace.ENGINE, engine=p.engine, backend=str(p.backend),
+                    lanes=1):
+        out = spec.fn(A, B, **p.kwargs_dict)
     out, stats = out if spec.returns_stats else (out, None)
     out = fi.corrupt("dispatch.execute", out,
                      engine=p.engine, backend=p.backend)
@@ -1531,59 +1534,69 @@ def _spz_batched(A: BatchedCSR, B: BatchedCSR, *, R: int = 16,
     stats = sg.SpzStats()
     lane_ok = np.asarray(A.valid) & np.asarray(B.valid)
     valid_lanes = [i for i in range(A.batch) if lane_ok[i]]
-    items = [(i, int(r)) for i in valid_lanes for r in range(A.n_rows)]
-    # only the host driver walks per-lane numpy copies; the fused driver
-    # reads the stacked device arrays directly
-    lanes = ({i: (csr_to_numpy(A[i]), csr_to_numpy(B[i]))
-              for i in valid_lanes} if driver == "host" else None)
-    work = None
-    if rsort or driver == "fused":
-        work = {i: sg.row_work(A[i], B[i]) for i in valid_lanes}
-    if rsort:
-        items.sort(key=lambda it: int(work[it[0]][it[1]]))
-    out_k = {it: np.empty(0, np.int32) for it in items}
-    out_v = {it: np.empty(0, np.float32) for it in items}
-    if driver == "fused":
-        mats = sg.fused_operands(A.indptr, A.indices, A.data,
-                                 B.indptr, B.indices, B.data)
-        for g0 in range(0, len(items), S):
-            group = items[g0:g0 + S]
-            plens = np.array([work[ln][r] for ln, r in group], np.int64)
-            sg.fused_process_group(group, plens, mats, R, bk, stats,
-                                   out_k, out_v)
-    else:
-        for g0 in range(0, len(items), S):
-            group = items[g0:g0 + S]
-            products = []
-            for lane, row in group:
-                (a_indptr, a_idx, a_val), (b_indptr, b_idx, b_val) = \
-                    lanes[lane]
-                products.extend(sg.expand_group(
-                    [row], a_indptr, a_idx, a_val, b_indptr, b_idx, b_val))
-            parts = sg.sort_phase(products, R, len(group), bk, stats,
-                                  cap_s=S)
-            final = sg.merge_tree_host(parts, R, bk, stats, cap_s=S)
-            if final is not None:
-                Kf, Vf, lf = final
-                for s, it in enumerate(group):
-                    out_k[it] = Kf[s, :lf[s]]
-                    out_v[it] = Vf[s, :lf[s]]
-    results = []
-    for i in range(A.batch):
-        if not lane_ok[i]:
-            results.append(None)
-            continue
-        rr, cc, vv = [], [], []
-        for row in range(A.n_rows):
-            k, v = out_k[(i, row)], out_v[(i, row)]
-            nz = v != 0.0
-            rr.append(np.full(int(nz.sum()), row, np.int64))
-            cc.append(k[nz])
-            vv.append(v[nz])
-        results.append(csr_from_coo(
-            np.concatenate(rr) if rr else [],
-            np.concatenate(cc) if cc else [],
-            np.concatenate(vv) if vv else [], (A.n_rows, B.n_cols)))
+    with trace.span(trace.SPZ_PREP,
+                    rows=len(valid_lanes) * A.n_rows) as span:
+        items = [(i, int(r)) for i in valid_lanes for r in range(A.n_rows)]
+        # only the host driver walks per-lane numpy copies; the fused
+        # driver reads the stacked device arrays directly
+        lanes = ({i: (csr_to_numpy(A[i]), csr_to_numpy(B[i]))
+                  for i in valid_lanes} if driver == "host" else None)
+        work = None
+        if rsort or driver == "fused":
+            work = {i: sg.row_work(A[i], B[i]) for i in valid_lanes}
+            span.set_metadata(products=int(sum(w.sum()
+                                               for w in work.values())))
+        if rsort:
+            items.sort(key=lambda it: int(work[it[0]][it[1]]))
+        out_k = {it: np.empty(0, np.int32) for it in items}
+        out_v = {it: np.empty(0, np.float32) for it in items}
+        if driver == "fused":
+            mats = sg.fused_operands(A.indptr, A.indices, A.data,
+                                     B.indptr, B.indices, B.data)
+    with trace.span(trace.SPZ_GROUPS, groups=-(-len(items) // S)):
+        if driver == "fused":
+            for g0 in range(0, len(items), S):
+                group = items[g0:g0 + S]
+                plens = np.array([work[ln][r] for ln, r in group], np.int64)
+                sg.fused_process_group(group, plens, mats, R, bk, stats,
+                                       out_k, out_v)
+        else:
+            for g0 in range(0, len(items), S):
+                group = items[g0:g0 + S]
+                products = []
+                for lane, row in group:
+                    (a_indptr, a_idx, a_val), (b_indptr, b_idx, b_val) = \
+                        lanes[lane]
+                    products.extend(sg.expand_group(
+                        [row], a_indptr, a_idx, a_val, b_indptr, b_idx,
+                        b_val))
+                parts = sg.sort_phase(products, R, len(group), bk, stats,
+                                      cap_s=S)
+                final = sg.merge_tree_host(parts, R, bk, stats, cap_s=S)
+                if final is not None:
+                    Kf, Vf, lf = final
+                    for s, it in enumerate(group):
+                        out_k[it] = Kf[s, :lf[s]]
+                        out_v[it] = Vf[s, :lf[s]]
+    with trace.span(trace.SPZ_ASSEMBLE) as span:
+        results, nnz_out = [], 0
+        for i in range(A.batch):
+            if not lane_ok[i]:
+                results.append(None)
+                continue
+            rr, cc, vv = [], [], []
+            for row in range(A.n_rows):
+                k, v = out_k[(i, row)], out_v[(i, row)]
+                nz = v != 0.0
+                rr.append(np.full(int(nz.sum()), row, np.int64))
+                cc.append(k[nz])
+                vv.append(v[nz])
+            cols = np.concatenate(cc) if cc else []
+            nnz_out += len(cols)
+            results.append(csr_from_coo(
+                np.concatenate(rr) if rr else [], cols,
+                np.concatenate(vv) if vv else [], (A.n_rows, B.n_cols)))
+        span.set_metadata(nnz_out=nnz_out)
     return results
 
 
@@ -1721,10 +1734,12 @@ def plan_batched(A: BatchedCSR, B: BatchedCSR, engine: str = "auto", *,
 def assemble_batched(outs: list, A: BatchedCSR, B: BatchedCSR) -> BatchedCSR:
     """Stack per-lane results (None = invalid lane) into the output
     BatchedCSR whose lane capacity is the max output nnz."""
-    empty = csr_from_coo([], [], [], (A.n_rows, B.n_cols))
-    cap = max(int(np.asarray(o.indptr)[-1]) for o in outs if o is not None)
-    batched = batch_csr([o if o is not None else empty for o in outs],
-                        nnz_cap=max(cap, 1))
+    with trace.span(trace.SHARD_ASSEMBLE, lanes=len(outs)):
+        empty = csr_from_coo([], [], [], (A.n_rows, B.n_cols))
+        cap = max(int(np.asarray(o.indptr)[-1]) for o in outs
+                  if o is not None)
+        batched = batch_csr([o if o is not None else empty for o in outs],
+                            nnz_cap=max(cap, 1))
     return BatchedCSR(batched.indptr, batched.indices, batched.data,
                       jnp.asarray(A.valid) & jnp.asarray(B.valid),
                       batched.shape)
@@ -1743,7 +1758,9 @@ def execute_batched(p: ExecutionPlan, A: BatchedCSR,
             f"plan/operand mismatch: planned {p.batch}x{p.a_shape} @ "
             f"{p.b_shape}, got {A.batch}x{A.shape} @ {B.shape}")
     fi.fire("dispatch.execute_batched", engine=p.engine, backend=p.backend)
-    outs = _BATCH_DRIVERS[p.engine](A, B, **p.kwargs_dict)
+    with trace.span(trace.ENGINE, engine=p.engine, backend=str(p.backend),
+                    lanes=A.batch):
+        outs = _BATCH_DRIVERS[p.engine](A, B, **p.kwargs_dict)
     outs = fi.corrupt("dispatch.execute_batched", outs,
                       engine=p.engine, backend=p.backend)
     return assemble_batched(outs, A, B)

@@ -33,6 +33,7 @@ import jax.numpy as jnp
 
 from repro.core.formats import CSR, EMPTY, csr_from_coo, csr_to_numpy, row_ids_from_indptr
 from repro.core import stream as kvstream
+from repro.core import trace
 from repro.kernels import backend as kb
 
 
@@ -200,7 +201,12 @@ def spgemm_esc(A: CSR, B: CSR, cap_products: int | None = None) -> CSR:
 @dataclasses.dataclass
 class SpzStats:
     """Dynamic instruction counts (Fig. 11), traffic (Fig. 10) and the
-    execution-time breakdown (Fig. 9)."""
+    execution-time breakdown (Fig. 9).
+
+    The four ``t_*`` fields are the host driver's wall-clock breakdown,
+    read by ``benchmarks/run.py::fig9``.  The fused driver fills only
+    ``t_preprocess`` and ``t_output``; its group loop is timed by the
+    ``repro.spz.*`` profiler spans (``core/trace.py``)."""
     n_mssort: int = 0        # sort-instruction issues (S-stream lock-step)
     n_mszip: int = 0         # zip-instruction issues
     sort_elems: int = 0      # key-value tuples moved through sort
@@ -435,11 +441,13 @@ def _fused_bucket_impl(row_ids, lane_ids, a_indptr, a_idx, a_val,
     single trace.  Returns (keys (N, L), vals, lens (N,), rounds) where
     rounds carries the per-(round, pair) merge counters (see
     kernels/merge_tree.py zip_merge_tree detailed mode)."""
-    keys, vals, plens = _fused_expand(row_ids, lane_ids, a_indptr, a_idx,
-                                      a_val, b_indptr, b_idx, b_val, wcum0,
-                                      L)
-    return kvstream.fused_sort_merge(keys, vals, plens, R=R,
-                                     backend=backend, detailed=True)
+    with jax.named_scope(trace.EXPAND):
+        keys, vals, plens = _fused_expand(row_ids, lane_ids, a_indptr, a_idx,
+                                          a_val, b_indptr, b_idx, b_val,
+                                          wcum0, L)
+    with jax.named_scope(trace.SORT_MERGE):
+        return kvstream.fused_sort_merge(keys, vals, plens, R=R,
+                                         backend=backend, detailed=True)
 
 
 # smallest stream count a bucket is padded to
@@ -480,63 +488,71 @@ def fused_process_group(items, plens, mats, R, backend, stats: SpzStats,
     approximate for this driver: the host tree passes odd partitions
     through for free, while the pow2 tree copies them through an empty
     merge."""
-    empty_k = np.empty(0, np.int32)
-    empty_v = np.empty(0, np.float32)
-    buckets: dict[int, list[int]] = {}
-    for ix, (it, pl) in enumerate(zip(items, plens)):
-        if pl == 0:
-            if coo is None:
-                out_k[it] = empty_k
-                out_v[it] = empty_v
-        else:
-            buckets.setdefault(_pow2_chunks(int(pl), R), []).append(ix)
-    if not buckets:
-        return
-    max_plen = int(plens.max())
-    n_used = -(-max_plen // R)
-    stats.n_mssort += n_used
-    stats.sort_elems += int(plens.sum())
-    stats.chunk_loads += n_used
-    stats.chunk_stores += n_used
-    n_rounds = max(buckets).bit_length() - 1
-    steps_acc = [np.zeros(max(buckets) >> (k + 1), np.int64)
-                 for k in range(n_rounds)]
-    tails_acc = [np.zeros((max(buckets) >> (k + 1), 2), np.int64)
-                 for k in range(n_rounds)]
-    zip_elems = 0
-    for C_b in sorted(buckets):
-        idxs = buckets[C_b]
-        # pow2 stream counts, at least MIN_BUCKET_STREAMS, bound the
-        # number of compiled bucket shapes; padding streams do no work
-        Nb = max(MIN_BUCKET_STREAMS, 1 << max(0, len(idxs) - 1).bit_length())
-        row_ids = np.full(Nb, -1, np.int32)
-        lane_ids = np.zeros(Nb, np.int32)
-        for t, ix in enumerate(idxs):
-            lane_ids[t], row_ids[t] = items[ix]
-        mk, mv, ml, rounds = _fused_bucket(
-            jnp.asarray(row_ids), jnp.asarray(lane_ids), *mats,
-            R=R, L=C_b * R, backend=kb.resolve_backend(backend).name)
-        mk, mv, ml = np.asarray(mk), np.asarray(mv), np.asarray(ml)
-        for k, (st, ze, tl) in enumerate(rounds):
-            st, tl = np.asarray(st), np.asarray(tl)
-            np.maximum(steps_acc[k][:len(st)], st,
-                       out=steps_acc[k][:len(st)])
-            np.maximum(tails_acc[k][:len(tl)], tl,
-                       out=tails_acc[k][:len(tl)])
-            zip_elems += int(np.asarray(ze))
-        if coo is not None:
-            valid = np.arange(mk.shape[1])[None, :] < ml[:, None]
-            coo.append((np.repeat(row_ids, ml), mk[valid], mv[valid]))
-        else:
-            for t, ix in enumerate(idxs):
-                it = items[ix]
-                out_k[it] = mk[t, :ml[t]]
-                out_v[it] = mv[t, :ml[t]]
-    n_zip = sum(int(s.sum()) for s in steps_acc)
-    stats.n_mszip += n_zip
-    stats.zip_elems += zip_elems
-    stats.chunk_loads += 2 * n_zip
-    stats.chunk_stores += n_zip + sum(int(t.sum()) for t in tails_acc)
+    with trace.span(trace.SPZ_GROUP, items=len(items),
+                    products=int(plens.sum())) as group_span:
+        empty_k = np.empty(0, np.int32)
+        empty_v = np.empty(0, np.float32)
+        buckets: dict[int, list[int]] = {}
+        for ix, (it, pl) in enumerate(zip(items, plens)):
+            if pl == 0:
+                if coo is None:
+                    out_k[it] = empty_k
+                    out_v[it] = empty_v
+            else:
+                buckets.setdefault(_pow2_chunks(int(pl), R), []).append(ix)
+        group_span.set_metadata(buckets=len(buckets))
+        if not buckets:
+            return
+        max_plen = int(plens.max())
+        n_used = -(-max_plen // R)
+        stats.n_mssort += n_used
+        stats.sort_elems += int(plens.sum())
+        stats.chunk_loads += n_used
+        stats.chunk_stores += n_used
+        n_rounds = max(buckets).bit_length() - 1
+        steps_acc = [np.zeros(max(buckets) >> (k + 1), np.int64)
+                     for k in range(n_rounds)]
+        tails_acc = [np.zeros((max(buckets) >> (k + 1), 2), np.int64)
+                     for k in range(n_rounds)]
+        zip_elems = 0
+        for C_b in sorted(buckets):
+            idxs = buckets[C_b]
+            # pow2 stream counts, at least MIN_BUCKET_STREAMS, bound the
+            # number of compiled bucket shapes; padding streams do no work
+            Nb = max(MIN_BUCKET_STREAMS, 1 << max(0, len(idxs) - 1).bit_length())
+            shape = {"streams": Nb, "used": len(idxs), "L": C_b * R}
+            with trace.span(trace.SPZ_LAUNCH, **shape):
+                row_ids = np.full(Nb, -1, np.int32)
+                lane_ids = np.zeros(Nb, np.int32)
+                for t, ix in enumerate(idxs):
+                    lane_ids[t], row_ids[t] = items[ix]
+                mk, mv, ml, rounds = _fused_bucket(
+                    jnp.asarray(row_ids), jnp.asarray(lane_ids), *mats,
+                    R=R, L=C_b * R, backend=kb.resolve_backend(backend).name)
+            with trace.span(trace.SPZ_FETCH, **shape):
+                mk, mv, ml = np.asarray(mk), np.asarray(mv), np.asarray(ml)
+                rounds = [(np.asarray(st), int(np.asarray(ze)), np.asarray(tl))
+                          for st, ze, tl in rounds]
+            with trace.span(trace.SPZ_UNPACK, **shape):
+                for k, (st, ze, tl) in enumerate(rounds):
+                    np.maximum(steps_acc[k][:len(st)], st,
+                               out=steps_acc[k][:len(st)])
+                    np.maximum(tails_acc[k][:len(tl)], tl,
+                               out=tails_acc[k][:len(tl)])
+                    zip_elems += ze
+                if coo is not None:
+                    valid = np.arange(mk.shape[1])[None, :] < ml[:, None]
+                    coo.append((np.repeat(row_ids, ml), mk[valid], mv[valid]))
+                else:
+                    for t, ix in enumerate(idxs):
+                        it = items[ix]
+                        out_k[it] = mk[t, :ml[t]]
+                        out_v[it] = mv[t, :ml[t]]
+        n_zip = sum(int(s.sum()) for s in steps_acc)
+        stats.n_mszip += n_zip
+        stats.zip_elems += zip_elems
+        stats.chunk_loads += 2 * n_zip
+        stats.chunk_stores += n_zip + sum(int(t.sum()) for t in tails_acc)
 
 
 def _group_cap(Sg: int, S: int) -> int:
@@ -578,49 +594,52 @@ def _spz_host_driver(A, B, R, S, order, backend, stats):
     return out_rows_k, out_rows_v
 
 
-def _spz_fused_driver(A, B, R, S, order, work, backend, stats):
+def _spz_fused_driver(A, R, S, order, work, mats, backend, stats):
     """Device-resident driver: per lock-step group, the work-bucketed
     expand/sort/merge-tree pipelines run as jitted computations keyed on
     static (N, L, R) buckets.  All chunk pointers live on the device;
-    SpzStats counts come back as device counters (wall-clock attribution
-    collapses into t_sort)."""
+    SpzStats counts come back as device counters."""
     coo: list = []
-    mats = fused_operands(A.indptr[None], A.indices[None], A.data[None],
-                          B.indptr[None], B.indices[None], B.data[None])
-    for g0 in range(0, A.n_rows, S):
-        rows = order[g0:g0 + S]
-        items = [(0, int(i)) for i in rows]
-        t1 = time.perf_counter()
-        fused_process_group(items, work[rows], mats, R, backend, stats,
-                            coo=coo)
-        stats.t_sort += time.perf_counter() - t1
+    with trace.span(trace.SPZ_GROUPS, groups=-(-A.n_rows // S)):
+        for g0 in range(0, A.n_rows, S):
+            rows = order[g0:g0 + S]
+            items = [(0, int(i)) for i in rows]
+            fused_process_group(items, work[rows], mats, R, backend, stats,
+                                coo=coo)
     return coo
 
 
 def _coo_parts_to_csr(coo, shape) -> CSR:
     """Assemble the fused driver's vectorized (rows, cols, vals) parts
     into the output CSR, dropping exact zeros like the scalar engines."""
-    if not coo:
-        return csr_from_coo([], [], [], shape)
-    rows = np.concatenate([p[0] for p in coo])
-    cols = np.concatenate([p[1] for p in coo])
-    vals = np.concatenate([p[2] for p in coo])
-    nz = vals != 0.0
-    return csr_from_coo(rows[nz], cols[nz], vals[nz], shape)
+    with trace.span(trace.SPZ_ASSEMBLE) as span:
+        if not coo:
+            span.set_metadata(nnz_out=0)
+            return csr_from_coo([], [], [], shape)
+        rows = np.concatenate([p[0] for p in coo])
+        cols = np.concatenate([p[1] for p in coo])
+        vals = np.concatenate([p[2] for p in coo])
+        nz = vals != 0.0
+        span.set_metadata(nnz_out=int(nz.sum()))
+        return csr_from_coo(rows[nz], cols[nz], vals[nz], shape)
 
 
 def _rows_to_csr(out_rows_k, out_rows_v, shape) -> CSR:
     """Assemble per-row key/value slices into the output CSR (empty-safe)."""
-    rr, cc, vv = [], [], []
-    for i, (k, v) in enumerate(zip(out_rows_k, out_rows_v)):
-        nz = v != 0.0
-        rr.append(np.full(int(nz.sum()), i, np.int64))
-        cc.append(k[nz])
-        vv.append(v[nz])
-    if not rr:
-        return csr_from_coo([], [], [], shape)
-    return csr_from_coo(np.concatenate(rr), np.concatenate(cc),
-                        np.concatenate(vv), shape)
+    with trace.span(trace.SPZ_ASSEMBLE) as span:
+        rr, cc, vv = [], [], []
+        for i, (k, v) in enumerate(zip(out_rows_k, out_rows_v)):
+            nz = v != 0.0
+            rr.append(np.full(int(nz.sum()), i, np.int64))
+            cc.append(k[nz])
+            vv.append(v[nz])
+        if not rr:
+            span.set_metadata(nnz_out=0)
+            return csr_from_coo([], [], [], shape)
+        cols = np.concatenate(cc)
+        span.set_metadata(nnz_out=len(cols))
+        return csr_from_coo(np.concatenate(rr), cols, np.concatenate(vv),
+                            shape)
 
 
 def spgemm_spz(A: CSR, B: CSR, *, R: int = 16, S: int | None = None,
@@ -656,9 +675,16 @@ def spgemm_spz(A: CSR, B: CSR, *, R: int = 16, S: int | None = None,
         # zero output rows: concatenating per-row results would raise
         return csr_from_coo([], [], [], (A.n_rows, B.n_cols)), stats
     t0 = time.perf_counter()
-    work = row_work(A, B) if (rsort or driver == "fused") else None
-    order = (np.argsort(work, kind="stable") if rsort
-             else np.arange(A.n_rows))
+    with trace.span(trace.SPZ_PREP, rows=A.n_rows) as span:
+        work = row_work(A, B) if (rsort or driver == "fused") else None
+        order = (np.argsort(work, kind="stable") if rsort
+                 else np.arange(A.n_rows))
+        if driver == "fused":
+            mats = fused_operands(A.indptr[None], A.indices[None],
+                                  A.data[None], B.indptr[None],
+                                  B.indices[None], B.data[None])
+        if work is not None:
+            span.set_metadata(products=int(work.sum()))
     stats.t_preprocess = time.perf_counter() - t0
     if driver == "host":
         out_rows_k, out_rows_v = _spz_host_driver(A, B, R, S, order, bk,
@@ -666,7 +692,7 @@ def spgemm_spz(A: CSR, B: CSR, *, R: int = 16, S: int | None = None,
         t3 = time.perf_counter()
         out = _rows_to_csr(out_rows_k, out_rows_v, (A.n_rows, B.n_cols))
     else:
-        coo = _spz_fused_driver(A, B, R, S, order, work, bk, stats)
+        coo = _spz_fused_driver(A, R, S, order, work, mats, bk, stats)
         t3 = time.perf_counter()
         out = _coo_parts_to_csr(coo, (A.n_rows, B.n_cols))
     stats.t_output = time.perf_counter() - t3

@@ -37,6 +37,7 @@ from jax.sharding import PartitionSpec as P
 
 from repro.core import dispatch as dp
 from repro.core import spgemm_engines as sg
+from repro.core import trace
 from repro.core.formats import EMPTY, BatchedCSR, csr_from_coo
 from repro.launch.mesh import make_lane_mesh
 from repro.runtime import faultinject as fi
@@ -248,7 +249,9 @@ def _execute_groups(sp: ShardPlan, A: BatchedCSR, B: BatchedCSR, *,
         fi.fire("shard.worker", device=device, engine=sp.base.engine)
         idx = np.asarray(lanes)
         dev = devices[device]
-        with jax.default_device(dev):
+        with jax.default_device(dev), trace.span(
+                trace.ENGINE, engine=sp.base.engine,
+                backend=str(sp.base.backend), lanes=len(lanes)):
             sub = driver(_lane_select(A, idx, dev), _lane_select(B, idx, dev),
                          **kw)
         for j, i in enumerate(lanes):

@@ -245,8 +245,9 @@ def stream_call(kernel, ins, outs, *, width, interpret):
     tiles; a block holds a power-of-two count of whole streams, at least
     one (8, 128) tile and at most about ``BLOCK_ELEMS`` elements unless
     one stream is wider; N is padded to whole blocks with the given pad
-    values.  ``kernel(*in_refs, *out_refs)`` sees (T, 128) blocks.
-    Returns the outputs as (N, width) arrays."""
+    values.  ``kernel(*in_refs, *out_refs)`` sees (T, 128) blocks; the
+    call is named after the kernel function (through a
+    ``functools.partial``).  Returns the outputs as (N, width) arrays."""
     N = ins[0][0].shape[0]
     assert width & (width - 1) == 0, f"stream width {width} must be a power of two"
     per_tile = max(1, SUBLANES * LANES // width)
@@ -259,6 +260,7 @@ def stream_call(kernel, ins, outs, *, width, interpret):
     spec = pl.BlockSpec((rows, LANES), lambda b: (b, 0))
     res = pl.pallas_call(
         kernel,
+        name=getattr(kernel, "func", kernel).__name__,
         grid=(Np // spb,),
         in_specs=[spec] * len(flat),
         out_specs=[spec] * len(outs),

@@ -66,6 +66,7 @@ from typing import Callable, Optional
 import numpy as np
 
 from repro.core import dispatch as dp
+from repro.core import trace
 from repro.core.formats import CSR, batch_csr, validate_operands
 from repro.distributed import spgemm_shard as shard
 from repro.runtime import faultinject as fi
@@ -288,23 +289,25 @@ class SpGemmService:
         :class:`~repro.core.formats.InvalidOperand` naming the field —
         they never reach a kernel, and never poison a co-bucketed
         batch."""
-        validate_operands(A, B)
-        with self._mu:
-            now = self.clock() if now is None else now
-            key = bucket_key(A, B)
-            req = SpGemmRequest(A=A, B=B, id=self._next_id, t_submit=now,
-                                bucket=key)
-            self._next_id += 1
-            self._by_id[req.id] = req
-            if self.warmer is not None:
-                self.warmer.observe(key, A, B)
-            q = self._queues.setdefault(key, [])
-            if not q:
-                self._opened[key] = now
-            q.append(req)
-            if len(q) >= self.max_batch:
-                self._flush(key, now, reason="full")
-            return req
+        with trace.span(trace.SERVE_SUBMIT) as span:
+            validate_operands(A, B)
+            with self._mu:
+                now = self.clock() if now is None else now
+                key = bucket_key(A, B)
+                req = SpGemmRequest(A=A, B=B, id=self._next_id,
+                                    t_submit=now, bucket=key)
+                span.set_metadata(request=req.id)
+                self._next_id += 1
+                self._by_id[req.id] = req
+                if self.warmer is not None:
+                    self.warmer.observe(key, A, B)
+                q = self._queues.setdefault(key, [])
+                if not q:
+                    self._opened[key] = now
+                q.append(req)
+                if len(q) >= self.max_batch:
+                    self._flush(key, now, reason="full")
+                return req
 
     def lookup(self, request_id: int) -> SpGemmRequest:
         """The request for an id — every submitted id resolves here,
@@ -403,17 +406,19 @@ class SpGemmService:
     def _check_outputs(out, reqs: list) -> None:
         """Screen every lane of a flush result; silent garbage (injected
         NaNs, out-of-range indices) counts as a failed attempt."""
-        for i in range(len(reqs)):
-            dp.check_result(out[i])
+        with trace.span(trace.SERVE_CHECK, lanes=len(reqs)):
+            for i in range(len(reqs)):
+                dp.check_result(out[i])
 
     def _run_batched(self, reqs: list, key: tuple, planner) -> object:
         """Build the padded batch for ``reqs`` and run one execution
         attempt through ``planner(A, B) -> (plan-ish, execute_fn)``."""
         _, _, cap_a, cap_b = key
-        A = batch_csr([r.A for r in reqs], nnz_cap=cap_a,
-                      batch_cap=self.max_batch)
-        B = batch_csr([r.B for r in reqs], nnz_cap=cap_b,
-                      batch_cap=self.max_batch)
+        with trace.span(trace.SERVE_BATCH, lanes=len(reqs)):
+            A = batch_csr([r.A for r in reqs], nnz_cap=cap_a,
+                          batch_cap=self.max_batch)
+            B = batch_csr([r.B for r in reqs], nnz_cap=cap_b,
+                          batch_cap=self.max_batch)
         return planner(A, B)
 
     def _flush(self, key: tuple, now: float, reason: str) -> int:
@@ -591,6 +596,13 @@ class SpGemmService:
         concurrent ladders — different buckets on executor threads —
         cannot interleave each other's state; ``_land`` applies the
         returned outcome under the service lock."""
+        with trace.span(trace.SERVE_FLUSH,
+                        requests=trace.ids(r.id for r in reqs),
+                        reason=reason, bucket=trace.bucket(key)):
+            return self._ladder(key, reqs, reason)
+
+    def _ladder(self, key: tuple, reqs: list, reason: str) -> _FlushOutcome:
+        """:meth:`_run_ladder`'s body, inside its flush span."""
         fi.fire("service.flush", bucket=key, reason=reason)
         results: dict[int, tuple] = {}
         dead: dict[int, tuple] = {}
@@ -626,11 +638,12 @@ class SpGemmService:
             try:
                 def planned(A, B):
                     nonlocal sp
-                    sp = shard.plan_sharded(A, B, self.engine,
-                                            mesh=self.mesh,
-                                            cache=self.cache,
-                                            rules=self.rules)
-                    sp = self._stick_bucket_cap(key, sp)
+                    with trace.span(trace.SERVE_PLAN, lanes=len(pending)):
+                        sp = shard.plan_sharded(A, B, self.engine,
+                                                mesh=self.mesh,
+                                                cache=self.cache,
+                                                rules=self.rules)
+                        sp = self._stick_bucket_cap(key, sp)
                     return shard.execute_sharded(sp, A, B)
                 out = self._run_batched([r for _, r in pending], key,
                                         planned)

@@ -63,3 +63,25 @@ def test_fused_bucket_compiles_for_v5e(one_chip, N, L):
     _compile(lambda k, v, n: fused_bucket_pallas(k, v, n, R=16,
                                                  detailed=True),
              one_chip, *_streams(N, L))
+
+
+def test_bucket_program_names_its_phases_and_kernel(one_chip):
+    """The whole bucket program of an m133-class 4096-row product: its
+    HLO carries the two device phase scopes of ``core/trace.py`` in
+    every operation's ``op_name``, and the Pallas call is named after
+    its kernel function, which is how a chip trace is split by phase."""
+    from repro.core import spgemm_engines as sg
+    from repro.core import trace
+    n, nnz, Nb = 4096, 16384, 8
+    shapes = [((Nb,), jnp.int32), ((Nb,), jnp.int32),
+              ((1, n + 1), jnp.int32), ((1, nnz), jnp.int32),
+              ((1, nnz), jnp.float32), ((1, n + 1), jnp.int32),
+              ((1, nnz), jnp.int32), ((1, nnz), jnp.float32),
+              ((1, nnz + 1), jnp.int32)]
+    args = [jax.ShapeDtypeStruct(s, dt, sharding=one_chip)
+            for s, dt in shapes]
+    text = sg._fused_bucket.lower(*args, R=16, L=16,
+                                  backend="pallas").compile().as_text()
+    for scope in (trace.EXPAND, trace.SORT_MERGE):
+        assert f'op_name="jit(_fused_bucket_impl)/{scope}/' in text, scope
+    assert "_fused_bucket_kernel" in text
