@@ -1548,18 +1548,20 @@ def _spz_batched(A: BatchedCSR, B: BatchedCSR, *, R: int = 16,
                                                for w in work.values())))
         if rsort:
             items.sort(key=lambda it: int(work[it[0]][it[1]]))
-        out_k = {it: np.empty(0, np.int32) for it in items}
-        out_v = {it: np.empty(0, np.float32) for it in items}
         if driver == "fused":
             mats = sg.fused_operands(A.indptr, A.indices, A.data,
                                      B.indptr, B.indices, B.data)
+        else:
+            out_k = {it: np.empty(0, np.int32) for it in items}
+            out_v = {it: np.empty(0, np.float32) for it in items}
     with trace.span(trace.SPZ_GROUPS, groups=-(-len(items) // S)):
         if driver == "fused":
+            runs: list = []
             for g0 in range(0, len(items), S):
                 group = items[g0:g0 + S]
                 plens = np.array([work[ln][r] for ln, r in group], np.int64)
                 sg.fused_process_group(group, plens, mats, R, bk, stats,
-                                       out_k, out_v)
+                                       runs)
         else:
             for g0 in range(0, len(items), S):
                 group = items[g0:g0 + S]
@@ -1578,6 +1580,9 @@ def _spz_batched(A: BatchedCSR, B: BatchedCSR, *, R: int = 16,
                     for s, it in enumerate(group):
                         out_k[it] = Kf[s, :lf[s]]
                         out_v[it] = Vf[s, :lf[s]]
+    if driver == "fused":
+        outs = sg._runs_to_csrs(runs, (A.n_rows, B.n_cols), A.batch)
+        return [c if lane_ok[i] else None for i, c in enumerate(outs)]
     with trace.span(trace.SPZ_ASSEMBLE) as span:
         results, nnz_out = [], 0
         for i in range(A.batch):

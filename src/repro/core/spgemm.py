@@ -465,16 +465,13 @@ def _pow2_chunks(max_plen: int, R: int) -> int:
 
 
 def fused_process_group(items, plens, mats, R, backend, stats: SpzStats,
-                         out_k: dict | None = None,
-                         out_v: dict | None = None,
-                         coo: list | None = None) -> None:
+                         runs: list) -> None:
     """Run one lock-step group of work items through the fused pipeline.
 
     items: [(lane, row)] output rows of the group; plens: per-item
-    product counts; mats: :func:`fused_operands`; results
-    land in out_k/out_v keyed by (lane, row), or — when ``coo`` is given
-    instead — as vectorized (rows, cols, vals) triples appended to it
-    (the single-matrix fast path: no per-row slicing).
+    product counts; mats: :func:`fused_operands`; each bucket appends
+    its merged streams to ``runs`` as one (lanes, rows, lens, keys, vals)
+    part, read by :func:`_runs_to_csrs` (no per-row slicing).
 
     Streams are bucketed by their own pow2 chunk count so a skewed group
     does not pad every stream to the group-max width (the fused analogue
@@ -490,15 +487,9 @@ def fused_process_group(items, plens, mats, R, backend, stats: SpzStats,
     merge."""
     with trace.span(trace.SPZ_GROUP, items=len(items),
                     products=int(plens.sum())) as group_span:
-        empty_k = np.empty(0, np.int32)
-        empty_v = np.empty(0, np.float32)
         buckets: dict[int, list[int]] = {}
-        for ix, (it, pl) in enumerate(zip(items, plens)):
-            if pl == 0:
-                if coo is None:
-                    out_k[it] = empty_k
-                    out_v[it] = empty_v
-            else:
+        for ix, pl in enumerate(plens):
+            if pl > 0:
                 buckets.setdefault(_pow2_chunks(int(pl), R), []).append(ix)
         group_span.set_metadata(buckets=len(buckets))
         if not buckets:
@@ -540,14 +531,11 @@ def fused_process_group(items, plens, mats, R, backend, stats: SpzStats,
                     np.maximum(tails_acc[k][:len(tl)], tl,
                                out=tails_acc[k][:len(tl)])
                     zip_elems += ze
-                if coo is not None:
-                    valid = np.arange(mk.shape[1])[None, :] < ml[:, None]
-                    coo.append((np.repeat(row_ids, ml), mk[valid], mv[valid]))
-                else:
-                    for t, ix in enumerate(idxs):
-                        it = items[ix]
-                        out_k[it] = mk[t, :ml[t]]
-                        out_v[it] = mv[t, :ml[t]]
+                n = len(idxs)
+                ml = ml[:n]
+                valid = np.arange(mk.shape[1])[None, :] < ml[:, None]
+                runs.append((lane_ids[:n], row_ids[:n], ml, mk[:n][valid],
+                             mv[:n][valid]))
         n_zip = sum(int(s.sum()) for s in steps_acc)
         stats.n_mszip += n_zip
         stats.zip_elems += zip_elems
@@ -599,29 +587,71 @@ def _spz_fused_driver(A, R, S, order, work, mats, backend, stats):
     expand/sort/merge-tree pipelines run as jitted computations keyed on
     static (N, L, R) buckets.  All chunk pointers live on the device;
     SpzStats counts come back as device counters."""
-    coo: list = []
+    runs: list = []
     with trace.span(trace.SPZ_GROUPS, groups=-(-A.n_rows // S)):
         for g0 in range(0, A.n_rows, S):
             rows = order[g0:g0 + S]
             items = [(0, int(i)) for i in rows]
             fused_process_group(items, work[rows], mats, R, backend, stats,
-                                coo=coo)
-    return coo
+                                runs)
+    return runs
 
 
-def _coo_parts_to_csr(coo, shape) -> CSR:
-    """Assemble the fused driver's vectorized (rows, cols, vals) parts
-    into the output CSR, dropping exact zeros like the scalar engines."""
+def _runs_to_csrs(runs, shape, n_lanes: int = 1) -> list[CSR]:
+    """Assemble the fused driver's merged streams into one output CSR per
+    lane, dropping exact zeros like the scalar engines.
+
+    runs: :func:`fused_process_group`'s parts; stream s of a part is row
+    ``rows[s]`` of lane ``lanes[s]``, its ``lens[s]`` entries next in
+    ``keys``/``vals``.  A merged stream's keys are sorted and unique, and
+    each (lane, row) is one stream, so the CSR follows from the run
+    lengths alone: ``indptr`` is a prefix sum of the rows' counts and
+    each run is copied to its row's offset, in whatever order the rows
+    came (rsort, buckets).  No sort, no per-row loop; the result equals
+    ``csr_from_coo`` of the same entries."""
+    n_rows = shape[0]
     with trace.span(trace.SPZ_ASSEMBLE) as span:
-        if not coo:
-            span.set_metadata(nnz_out=0)
-            return csr_from_coo([], [], [], shape)
-        rows = np.concatenate([p[0] for p in coo])
-        cols = np.concatenate([p[1] for p in coo])
-        vals = np.concatenate([p[2] for p in coo])
-        nz = vals != 0.0
-        span.set_metadata(nnz_out=int(nz.sum()))
-        return csr_from_coo(rows[nz], cols[nz], vals[nz], shape)
+        lanes, rows, lens, keys, vals = (
+            np.concatenate([p[k] for p in runs]) if runs
+            else np.zeros(0, dt) for k, dt in enumerate(
+                (np.int32, np.int32, np.int32, np.int32, np.float32)))
+        # exact zeros are rare: count them per run and drop them
+        zeros = np.flatnonzero(vals == 0.0)
+        kept = lens - np.bincount(
+            np.searchsorted(np.cumsum(lens), zeros, side="right"),
+            minlength=len(lens))
+        if len(zeros):
+            keys, vals = np.delete(keys, zeros), np.delete(vals, zeros)
+        slot = lanes.astype(np.int64) * n_rows + rows
+        counts = np.bincount(slot, weights=kept, minlength=n_lanes * n_rows)
+        indptr = np.zeros((n_lanes, n_rows + 1), np.int64)
+        np.cumsum(counts.reshape(n_lanes, n_rows).astype(np.int64), axis=1,
+                  out=indptr[:, 1:])
+        # the runs in output order, from a table over every (lane, row):
+        # one run a slot, so no sort
+        live = np.flatnonzero(kept)
+        run_at = np.full(n_lanes * n_rows, -1, np.int64)
+        run_at[slot[live]] = live
+        order = run_at[run_at >= 0]
+        # output entry j is kept entry src[j]: src steps by 1 inside a run
+        # and jumps from one run's last entry to the next run's first
+        k_o = kept[order]
+        b_o = (np.cumsum(kept) - kept)[order]
+        src = np.ones(len(keys), np.int64)
+        src[np.cumsum(k_o) - k_o] = b_o - np.concatenate(
+            [[0], (b_o + k_o - 1)[:-1]])
+        np.cumsum(src, out=src)
+        cols, data = keys[src], vals[src]
+        nnz = indptr[:, -1]
+        span.set_metadata(nnz_out=len(cols))
+        out = []
+        for ln, o in enumerate(np.cumsum(nnz) - nnz):
+            n = int(nnz[ln])
+            ix, dv = ((cols[o:o + n], data[o:o + n]) if n else
+                      (np.full(1, EMPTY, np.int32), np.zeros(1, np.float32)))
+            out.append(CSR(jnp.asarray(indptr[ln].astype(np.int32)),
+                           jnp.asarray(ix), jnp.asarray(dv), shape))
+        return out
 
 
 def _rows_to_csr(out_rows_k, out_rows_v, shape) -> CSR:
@@ -692,9 +722,9 @@ def spgemm_spz(A: CSR, B: CSR, *, R: int = 16, S: int | None = None,
         t3 = time.perf_counter()
         out = _rows_to_csr(out_rows_k, out_rows_v, (A.n_rows, B.n_cols))
     else:
-        coo = _spz_fused_driver(A, R, S, order, work, mats, bk, stats)
+        runs = _spz_fused_driver(A, R, S, order, work, mats, bk, stats)
         t3 = time.perf_counter()
-        out = _coo_parts_to_csr(coo, (A.n_rows, B.n_cols))
+        out, = _runs_to_csrs(runs, (A.n_rows, B.n_cols))
     stats.t_output = time.perf_counter() - t3
     return out, stats
 

@@ -21,9 +21,9 @@ Spans, where they open, their stats, and what reads them
 ``repro.spz.prep``
     the spz drivers' set-up: ``row_work``, the row order and
     ``fused_operands`` (``spgemm_spz``); the per-lane ``row_work``, the
-    ``(lane, row)`` items, the output dicts and ``fused_operands``
-    (``dispatch._spz_batched``).  ``rows``, ``products``.  Read as
-    ``driver.prep_ms``.
+    ``(lane, row)`` items and ``fused_operands``, or the host driver's
+    per-lane copies and output dicts (``dispatch._spz_batched``).
+    ``rows``, ``products``.  Read as ``driver.prep_ms``.
 ``repro.spz.groups``
     the whole lock-step group loop.  ``groups``.  Device-idle time
     inside it is ``driver.idle_ms``.
@@ -33,14 +33,18 @@ Spans, where they open, their stats, and what reads them
 ``repro.spz.launch`` / ``repro.spz.fetch`` / ``repro.spz.unpack``
     in each bucket of a group: the ``_fused_bucket`` call; the copies
     of its keys, values, lengths and round counters to the host; the
-    counters' reduction and the COO / ``out_k`` unpacking.
+    counters' reduction and the bucket's merged runs added to the
+    group's parts.
     ``streams`` (the padded stream count), ``used`` (real streams),
     ``L`` (stream width).
 ``repro.spz.assemble``
-    the output CSR: ``_coo_parts_to_csr`` / ``_rows_to_csr``, and
-    ``_spz_batched``'s per-lane, per-row output loop with its
-    ``csr_from_coo``.  ``nnz_out`` (nonzeros handed to
-    ``csr_from_coo``).  Read as ``output.assemble_ms``.
+    the output CSR of every lane.  The fused drivers (single and
+    batched) build it in ``_runs_to_csrs`` without a sort: ``indptr`` a
+    prefix sum of the merged runs' lengths, each run copied to its row's
+    offset.  The host drivers keep ``_rows_to_csr`` and
+    ``_spz_batched``'s per-row loop with ``csr_from_coo``.  ``nnz_out``
+    (the output's nonzeros, over all lanes).  Read as
+    ``output.assemble_ms``.
 ``repro.serve.submit``
     ``SpGemmService.submit`` (a flush it triggers runs inside it).
     ``request`` (the id).

@@ -128,15 +128,95 @@ def test_dispatch_spz_fused_engine():
     assert stats is not None and stats.n_mssort > 0
 
 
-def test_batched_fused_matches_host_batched():
+@pytest.mark.parametrize("rsort", [False, True])
+def test_batched_fused_matches_host_batched(rsort):
     mats = [random_sparse(32, 32, d, seed=i)
             for i, d in enumerate((0.01, 0.06, 0.02))]
     A = batch_csr(mats, batch_cap=len(mats) + 1)
-    out_f = dp.spgemm_batched(A, A, engine="spz-fused", R=8, S=32)
-    out_h = dp.spgemm_batched(A, A, engine="spz-host", R=8, S=32)
+    kw = dict(R=8, S=32, rsort=rsort)
+    out_f = dp.spgemm_batched(A, A, engine="spz-fused", **kw)
+    out_h = dp.spgemm_batched(A, A, engine="spz-host", **kw)
     for i in range(len(mats)):
         for h, f in zip(_csr_arrays(out_h[i]), _csr_arrays(out_f[i])):
             np.testing.assert_array_equal(h, f)
+
+
+# ---------------------------------------------------------------------------
+# output assembly from merged stream runs
+# ---------------------------------------------------------------------------
+
+def _cancelling():
+    """Row 1 of A @ A cancels to exactly 0.0 in column 0; rows 2-3 empty."""
+    return csr_from_coo([0, 0, 1, 1, 2], [0, 1, 0, 1, 3],
+                        [1.0, 1.0, 1.0, -1.0, 2.0], (4, 4))
+
+
+_RUN_CASES = {
+    "uniform": lambda: (random_sparse(96, 96, 0.03, seed=11), {}),
+    # small groups of skewed rows: several buckets per group
+    "powerlaw": lambda: (random_sparse(128, 128, 0.04, seed=9,
+                                       pattern="powerlaw"), dict(S=32)),
+    "rsort": lambda: (random_sparse(128, 128, 0.04, seed=9,
+                                    pattern="powerlaw"),
+                      dict(S=16, rsort=True)),
+    "empty_rows": lambda: (csr_from_coo([1, 1, 5], [0, 3, 2],
+                                        [1.0, 2.0, 3.0], (8, 8)), {}),
+    "all_zero": lambda: (csr_from_coo([], [], [], (8, 8)), {}),
+    "cancel": lambda: (_cancelling(), {}),
+    "batched": lambda: (batch_csr([random_sparse(32, 32, d, seed=i)
+                                   for i, d in enumerate((0.01, 0.06, 0.0,
+                                                          0.02))],
+                                  batch_cap=6), dict(S=32)),
+}
+
+
+@pytest.mark.parametrize("case", sorted(_RUN_CASES))
+def test_runs_to_csrs_bit_identical_to_csr_from_coo(monkeypatch, case):
+    """The sort-free build gives, lane by lane, exactly the CSR that
+    ``csr_from_coo`` builds from the same fused parts: indptr, padded
+    indices and data, dtypes and capacity."""
+    calls = []
+    real = sg._runs_to_csrs
+
+    def spy(runs, shape, n_lanes=1):
+        out = real(runs, shape, n_lanes)
+        calls.append((runs, shape, n_lanes, out))
+        return out
+
+    monkeypatch.setattr(sg, "_runs_to_csrs", spy)
+    A, kw = _RUN_CASES[case]()
+    if case == "batched":
+        got = dp._spz_batched(A, A, R=8, backend="xla", **kw)
+        lane_ok = np.asarray(A.valid)
+        assert [g is None for g in got] == [not v for v in lane_ok]
+    else:
+        got = [sg.spgemm_spz(A, A, R=8, backend="xla", driver="fused",
+                             **kw)[0]]
+    (runs, shape, n_lanes, out), = calls
+    lanes, rows, lens, keys, vals = (
+        np.concatenate([p[k] for p in runs]) if runs else np.zeros(0, dt)
+        for k, dt in enumerate((np.int32, np.int32, np.int32, np.int32,
+                                np.float32)))
+    # the build's premise: each (lane, row) is one sorted, unique run
+    assert len(set(zip(lanes.tolist(), rows.tolist()))) == len(rows)
+    for k in np.split(keys, np.cumsum(lens)[:-1]):
+        assert (np.diff(k) > 0).all()
+    if case == "cancel":
+        assert (vals == 0.0).any()
+    if case == "all_zero":
+        assert len(keys) == 0
+    r, ln = np.repeat(rows, lens), np.repeat(lanes, lens)
+    assert len(out) == n_lanes
+    for i, c in enumerate(out):
+        m = (ln == i) & (vals != 0.0)
+        want = csr_from_coo(r[m], keys[m], vals[m], shape)
+        for w, g in zip((want.indptr, want.indices, want.data),
+                        (c.indptr, c.indices, c.data)):
+            assert np.asarray(w).dtype == np.asarray(g).dtype
+            np.testing.assert_array_equal(np.asarray(w), np.asarray(g))
+    for g, c in zip(got, out):
+        if g is not None:
+            assert g is c
 
 
 # ---------------------------------------------------------------------------
