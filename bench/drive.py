@@ -51,6 +51,10 @@ class Run:
     flushes: list = dataclasses.field(default_factory=list)
     plans: set = dataclasses.field(default_factory=set)
     trace: object = None          # trace_reduce.Reduction, with --trace 1
+    program: object = None        # program_trace.ProgramReduction, idem
+    # after the check, of each completed operation: its operand's host
+    # (indptr, indices) and the reference product's nnz
+    operands: list = dataclasses.field(default_factory=list)
     least_bytes: float | None = None   # mean over the window's operations
     least_flops: float | None = None
     peak: dict | None = None

@@ -13,10 +13,22 @@ rows and columns: each pattern is generated once from the configuration's
 (``P A P^T``, whose square is ``P A^2 P^T``) and the values.  So every
 seed gives the program the same sizes, the same work per row and the
 same compiled shapes, in another order and with other numbers.
+
+A configuration names its generator (``generator.name``): ``regular``
+lives here; any other name is the file ``bench/gen/<name>.py``, whose
+``generate(rows, seed, **params)`` returns ``(indptr, indices, data)``
+as ``regular`` does.  So a new class of matrix comes with a file of its
+own and no edit here.
 """
 from __future__ import annotations
 
+import importlib.util
+import os
+import re
+
 import numpy as np
+
+GEN_DIR = os.path.dirname(os.path.abspath(__file__))
 
 
 def coo_to_csr(rows, cols, vals, shape):
@@ -51,11 +63,27 @@ def regular(rows: int, per_row: int, seed):
 GENERATORS = {"regular": regular}
 
 
+def generator(name: str):
+    """The generator a configuration names: one of :data:`GENERATORS`,
+    else ``generate`` of ``GEN_DIR/<name>.py``."""
+    if name in GENERATORS:
+        return GENERATORS[name]
+    if not re.fullmatch(r"[A-Za-z0-9_]+", name):
+        raise ValueError(f"generator name {name!r} is not a module name")
+    path = os.path.join(GEN_DIR, name + ".py")
+    if not os.path.exists(path):
+        raise FileNotFoundError(f"no generator {name!r}: {path} is not there")
+    spec = importlib.util.spec_from_file_location("bench_gen_" + name, path)
+    mod = importlib.util.module_from_spec(spec)
+    spec.loader.exec_module(mod)
+    return mod.generate
+
+
 def structure(config: dict, rows: int, pattern: int):
     """Pattern ``pattern`` of ``config``'s class with ``rows`` rows (its
     published row count, or fewer for a rehearsal)."""
     g = config["generator"]
-    return GENERATORS[g["name"]](
+    return generator(g["name"])(
         rows, seed=[config["structure_seed"], pattern], **g["params"])
 
 

@@ -1,0 +1,8 @@
+import program_trace
+
+
+def read(run):
+    """Host time in ``repro.spz.prep`` per product or per flush, in ms."""
+    if run.program is None:
+        return None
+    return program_trace.metrics(run.program).get("driver.prep_ms")

@@ -31,7 +31,7 @@ import jax  # noqa: E402
 import drive  # noqa: E402
 import program_trace  # noqa: E402
 import run as bench_run  # noqa: E402
-from trace_reduce import find_xplane, reduce_file  # noqa: E402
+from trace_reduce import find_xplane  # noqa: E402
 
 
 def main(argv=None) -> int:
@@ -66,7 +66,7 @@ def main(argv=None) -> int:
     path = os.path.join(args.out_dir, args.workload + ".xplane.pb")
     shutil.copy(find_xplane(tmp), path)
     shutil.rmtree(tmp, ignore_errors=True)
-    run.trace = reduce_file(path)
+    bench_run.read_trace(run, path)
     done = len(run.done)
     per_layer = {}
     for m in spec["per_layer"]:
@@ -80,7 +80,7 @@ def main(argv=None) -> int:
         "traced": {"product_s": run.window_s / done if done else None,
                    "req_per_s": done / run.window_s},
         "per_layer": per_layer,
-        "program": program_trace.summary(program_trace.reduce_file(path)),
+        "program": program_trace.summary(run.program),
     }, default=str), flush=True)
     return 0 if done == len(run.ops) else 1
 

@@ -19,12 +19,17 @@ spans) where the trace has products, else per flush
 (``repro.serve.flush`` spans):
 
 - ``driver.prep_ms``: host time in ``repro.spz.prep``;
-- ``driver.idle_ms``: device-idle time inside ``repro.spz.groups``;
+- ``driver.idle_ms``: device-idle time inside ``repro.spz.groups`` (no
+  device busy; left out of a trace with no device plane);
 - ``output.assemble_ms``: host time in ``repro.spz.assemble``;
 - ``serve.queue_ms``: mean over requests of the start of the flush whose
   ``requests`` holds the id less the start of its ``repro.serve.submit``;
 - ``device.expand_ms``, ``device.sort_merge_ms``: device time of the
-  operations under ``spz.expand`` and ``spz.sort_merge``.
+  operations under ``spz.expand`` and ``spz.sort_merge``, summed over
+  the devices.
+
+On several devices a scope's time is taken on each device's own
+timeline and summed; the device is idle where none of them is busy.
 """
 from __future__ import annotations
 
@@ -42,6 +47,8 @@ BUCKET_MODULE = "jit__fused_bucket_impl"
 METADATA_PLANE = "/host:metadata"
 # the phases of one product (inside ``bench.execute``) and of one flush
 PRODUCT_PHASES = ("repro.spz.prep", "repro.spz.groups", "repro.spz.assemble")
+# the spans ``metrics`` reads inside a product or a flush
+UNIT_PHASES = ("repro.spz.prep", "repro.spz.groups", "repro.spz.assemble")
 FLUSH_PHASES = ("repro.serve.batch", "repro.serve.plan", "repro.engine",
                 "repro.shard.assemble", "repro.serve.check")
 
@@ -64,17 +71,19 @@ class ProgramReduction:
     window: tuple            # (start, end) ns
     program_spans: list      # [Span] of repro.*, inside the window
     bench_spans: list        # [Span] of bench.*, inside the window
-    idle: list               # [(start, end)] ns: the device's idle gaps
+    idle: list               # [(start, end)] ns: no device busy
     gaps: list               # [(label, seconds)], longest first
-    scope_iv: dict           # scope ("" for none) -> [(start, end)] ns
+    scope_iv: dict           # scope ("" for none) -> {device plane:
+    #                          [(start, end)] ns, sorted and disjoint}
     module_s: dict           # module -> device seconds
     unscoped_s: dict         # instruction -> device seconds, no scope
     has_hlo: bool            # the trace carried the modules' HLO
+    device_busy_s: dict      # device plane -> busy seconds in the window
 
     @property
     def scope_s(self) -> dict:
-        """Scope -> device seconds in the window."""
-        return {k: sum(e - s for s, e in v) * 1e-9
+        """Scope -> device seconds in the window, over all devices."""
+        return {k: sum(e - s for iv in v.values() for s, e in iv) * 1e-9
                 for k, v in self.scope_iv.items()}
 
 
@@ -323,10 +332,11 @@ def reduce_profile(pd, hlo: dict | None = None) -> ProgramReduction:
                       key=lambda s: (s.start, -s.end))
     bench = [s for s in inside(bench) if s.name != WINDOW]
     program = inside(program)
-    busy, scoped, module_s, unscoped = [], [], {}, {}
+    busy, scope_iv, module_s, unscoped, device_busy = [], {}, {}, {}, {}
     for plane in pd.planes:
         if not DEVICE_PLANE.match(plane.name):
             continue
+        scoped, plane_busy = [], []
         lines = {line.name: list(line.events) for line in plane.lines}
         mods = sorted((e.start_ns, e.start_ns + e.duration_ns, e.name)
                       for e in lines.get(MODULE_LINE, []))
@@ -341,7 +351,7 @@ def reduce_profile(pd, hlo: dict | None = None) -> ProgramReduction:
             if not (e > w0 and s < w1):
                 continue
             s, e = max(s, w0), min(e, w1)
-            busy.append((s, e))
+            plane_busy.append((s, e))
             j = bisect.bisect_right(starts, ev.start_ns) - 1
             scopes = hlo.get(_program_id(mods[j][2])) if j >= 0 else None
             scope = (scopes or {}).get(_instruction(ev.name), "")
@@ -349,10 +359,15 @@ def reduce_profile(pd, hlo: dict | None = None) -> ProgramReduction:
             if not scope:
                 op = _instruction(ev.name)
                 unscoped[op] = unscoped.get(op, 0.0) + (e - s) * 1e-9
+        if not plane_busy:
+            continue
+        busy += plane_busy
+        device_busy[plane.name] = 1e-9 * sum(
+            e - s for s, e in _union(plane_busy))
+        for s, e, scope in _exclusive(scoped):
+            scope_iv.setdefault(scope, {}).setdefault(
+                plane.name, []).append((s, e))
     busy = _union(busy)
-    scope_iv: dict = {}
-    for s, e, scope in _exclusive(scoped):
-        scope_iv.setdefault(scope, []).append((s, e))
     idle, t = [], w0
     for s, e in busy + [[w1, w1]]:
         if s > t:
@@ -363,10 +378,11 @@ def reduce_profile(pd, hlo: dict | None = None) -> ProgramReduction:
                    in zip(labels, idle)), key=lambda g: -g[1])
     return ProgramReduction(window=(w0, w1), program_spans=program,
                             bench_spans=bench, idle=idle, gaps=gaps,
-                            scope_iv={k: [tuple(iv) for iv in _union(v)]
-                                      for k, v in scope_iv.items()},
+                            scope_iv={k: {d: [tuple(iv) for iv in _union(v)]
+                                          for d, v in by_dev.items()}
+                                      for k, by_dev in scope_iv.items()},
                             module_s=module_s, unscoped_s=unscoped,
-                            has_hlo=bool(hlo))
+                            has_hlo=bool(hlo), device_busy_s=device_busy)
 
 
 def _exclusive(ops):
@@ -507,7 +523,8 @@ def metrics(r: ProgramReduction, unit: str | None = None) -> dict:
     out = {"unit": unit, "units": n}
     if not n:
         return out
-    mine = _within(r.program_spans, parents)
+    mine = _within([s for s in r.program_spans if s.name in UNIT_PHASES],
+                   parents)
 
     def spans(name):
         return [s for s in mine if s.name == name]
@@ -519,14 +536,16 @@ def metrics(r: ProgramReduction, unit: str | None = None) -> dict:
     values = {
         "driver.prep_ms": host_ms("repro.spz.prep"),
         "driver.idle_ms": (1e3 * _overlap(r.idle, spans("repro.spz.groups"))
-                           / n if spans("repro.spz.groups") else None),
+                           / n if spans("repro.spz.groups")
+                           and r.device_busy_s else None),
         "output.assemble_ms": host_ms("repro.spz.assemble"),
         "serve.queue_ms": queue_ms(r) if unit == "flush" else None,
     }
     if any(r.scope_iv.get(scope) for scope in SCOPES):
         for scope in SCOPES:
-            values[f"device.{scope.split('.', 1)[1]}_ms"] = 1e3 * _overlap(
-                r.scope_iv.get(scope, []), parents) / n
+            values[f"device.{scope.split('.', 1)[1]}_ms"] = 1e3 * sum(
+                _overlap(iv, parents)
+                for iv in r.scope_iv.get(scope, {}).values()) / n
     out.update({k: v for k, v in values.items() if v is not None})
     return out
 

@@ -3,7 +3,8 @@
     python3 bench/run.py --workload m133.a2 --seed 7 --seconds 10 --trace 0
 
 Everything per cell is found by name from ``BENCHMARK.json``: the
-configuration in ``bench/configs/<config>.json``, the traffic mix in
+configuration in ``bench/configs/<config>.json`` (its matrices from the
+generator it names, ``bench/gen/<name>.py``), the traffic mix in
 ``bench/traffic/<traffic>.json`` (read by ``drive.py``, the one
 generator) and each metric's reader in ``bench/metrics/<name>.py``, or,
 for a quantity split by cell (``<quantity>.<part>``), in
@@ -13,7 +14,11 @@ A run builds its operands from ``--seed``, warms every shape its traffic
 uses (set-up), measures a window of ``--seconds``, then compares every
 answer of the window with the plain reference (``reference.py``).
 ``--trace 0`` reports the cell's end-to-end metrics; ``--trace 1``
-records the window with the profiler and reports its per-layer metrics.
+records the window with the profiler and reports its per-layer metrics,
+read from the run: ``run.trace`` (``trace_reduce.py``: device busy time,
+the benchmark's spans), ``run.program`` (``program_trace.py``: the
+program's spans and device scopes) and ``run.operands`` (each completed
+operation's operand and reference size, for counting least work).
 The last line of standard output is one JSON object; the numbers
 compared, each beside its limit, are the last lines of standard error.
 Without a TPU, or with fewer chips than the cell asks for, the run
@@ -155,7 +160,7 @@ def measure(args, spec, t_start: float):
     the answers on the host, and the device's memory peak."""
     import jax
     import drive
-    from trace_reduce import find_xplane, reduce_file
+    from trace_reduce import find_xplane
 
     d0, devs, peak = device_check(spec["cell"]["chips"], args.rehearse)
     drive.log(f"bench: workload={args.workload} seed={args.seed} "
@@ -197,7 +202,9 @@ def measure(args, spec, t_start: float):
     compiles.phase = "after"
     if args.trace:
         jax.profiler.stop_trace()
-    memory_peak = (d0.memory_stats() or {}).get("peak_bytes_in_use")
+    # the fullest device's peak
+    memory_peak = max((p for p in ((d.memory_stats() or {}).get(
+        "peak_bytes_in_use") for d in devs) if p is not None), default=None)
     entry.close()
     drive.log(f"bench: {len(run.done)} of {len(run.ops)} operations "
               f"completed in {len(rounds)} rounds; window {run.window_s!r} "
@@ -225,34 +232,48 @@ def measure(args, spec, t_start: float):
         op.output = None
     pool.release()
     if args.trace:
-        run.trace = reduce_file(find_xplane(tmp))
+        read_trace(run, find_xplane(tmp))
         shutil.rmtree(tmp, ignore_errors=True)
     return run, pool, outputs, (d0, devs, memory_peak)
 
 
+def read_trace(run, path: str) -> None:
+    """The window's trace reduced twice, for the readers: the device's
+    busy time and the benchmark's spans (``run.trace``), and the
+    program's spans and device scopes (``run.program``)."""
+    from jax.profiler import ProfileData
+    import drive
+    import program_trace
+    import trace_reduce
+    t = time.time()
+    with open(path, "rb") as f:
+        space = f.read()
+    pd = ProfileData.from_serialized_xspace(space)
+    run.trace = trace_reduce.reduce_profile(pd)
+    run.program = program_trace.reduce_profile(pd,
+                                               program_trace.trace_hlo(space))
+    engines = program_trace.named(run.program, "repro.engine")
+    drive.log(f"bench: trace read in {time.time() - t:.3f} s; device busy "
+              f"seconds {run.program.device_busy_s}; {len(engines)} engine "
+              f"calls of {sorted({e.stats.get('lanes') for e in engines})} "
+              "lanes")
+
+
 def check(run, pool, outputs) -> dict:
-    """Compare every answer with the reference; note the least work of
-    the window's operations on ``run``."""
+    """Compare every answer with the reference; leave each completed
+    operation's operand and reference size on ``run.operands``, and the
+    window's least work on ``run``."""
     import reference as refm
-    import work
+    import window_work
     rows = pool.shape[0]
     refs, readings = {}, []
     for k, (indptr, indices, data) in outputs:
         if k not in refs:
             refs[k] = refm.reference(*pool.host[k], pool.shape)
         readings.append(refm.compare(indptr, indices, data, rows, refs[k]))
-    nbytes, nflops = [], []
-    for op in run.done:
-        indptr, indices, _ = pool.host[op.pool_index]
-        products = work.partial_products(indptr, indices, indptr)
-        nnz = len(indices)
-        nbytes.append(work.least_bytes(rows, nnz, rows, nnz, rows,
-                                       refs[op.pool_index].nnz,
-                                       products))
-        nflops.append(work.flops(products))
-    if nbytes:
-        run.least_bytes = sum(nbytes) / len(nbytes)
-        run.least_flops = sum(nflops) / len(nflops)
+    run.operands = [(*pool.host[op.pool_index][:2], refs[op.pool_index].nnz)
+                    for op in run.done]
+    run.least_bytes, run.least_flops = window_work.least(run)
     return refm.worst(readings)
 
 
@@ -279,7 +300,7 @@ def result_line(args, spec, run, outputs, numbers, device) -> dict:
         mods = sorted(run.trace.modules.items(), key=lambda m: -m[1][1])
         result["breakdown"] = {
             "device_ops": [[n, s] for n, (_, s) in mods[:TOP]],
-            "idle_gaps": [[n, s] for n, s in run.trace.gaps[:TOP]]}
+            "idle_gaps": [[n, s] for n, s in run.program.gaps[:TOP]]}
     if args.rehearse:   # no CPU number under a metric's name
         result = {"rehearsal": True, **result, "metrics": {},
                   "metrics_read": sorted(metrics)}

@@ -1,11 +1,14 @@
 """The benchmark's own data, its reference and its yardstick."""
+import hashlib
 import json
 import os
+from types import SimpleNamespace as NS
 
 import numpy as np
 import pytest
 
 import reference as refm
+import window_work
 import work
 from gen import matrices
 
@@ -49,6 +52,69 @@ def test_every_seed_gives_the_same_sizes_in_another_order():
     assert np.array_equal(works[0], works[1])
 
 
+def _digest(*arrays):
+    d = hashlib.sha256()
+    for a in arrays:
+        a = np.ascontiguousarray(a)
+        d.update(str(a.dtype).encode())
+        d.update(a.tobytes())
+    return d.hexdigest()
+
+
+def test_m133_arrays_are_bit_identical_to_those_of_the_first_benchmark():
+    cfg = _config("m133-b3")
+    assert _digest(*matrices.structure(cfg, cfg["rows"], 0)) == \
+        "818a33c8b51a4f172ef1e8ced35c8bd64b2925df2efd9e93e9d199b0440b9573"
+    indptr, indices, values = matrices.build(cfg, cfg["rows"], 2**31 + 5, 3,
+                                             2)
+    assert _digest(indptr, indices, *values) == \
+        "2ce70685be309302a014d974a7b32e715b31e6354b11ebdbef511567b9303929"
+
+
+BAND = '''
+import numpy as np
+
+
+def generate(rows, seed, width):
+    """A band of ``2 * width + 1`` diagonals, values from the seed."""
+    r = np.repeat(np.arange(rows), 2 * width + 1)
+    c = r + np.tile(np.arange(-width, width + 1), rows)
+    keep = (c >= 0) & (c < rows)
+    r, c = r[keep], c[keep]
+    indptr = np.searchsorted(r, np.arange(rows + 1)).astype(np.int32)
+    v = np.random.default_rng(seed).standard_normal(len(c))
+    return indptr, c.astype(np.int32), v.astype(np.float32)
+'''
+
+
+def test_a_generator_file_is_found_and_built_through_the_pool(
+        tmp_path, monkeypatch):
+    import drive
+    (tmp_path / "gen").mkdir()
+    (tmp_path / "gen" / "band.py").write_text(BAND)
+    monkeypatch.setattr(matrices, "GEN_DIR", str(tmp_path / "gen"))
+    cfg = {"rows": 40, "structure_seed": 5,
+           "generator": {"name": "band", "params": {"width": 2}}}
+    traffic = {"patterns": 2, "value_sets": 2}
+    pool = drive.Pool(cfg, traffic, 2**31 + 9, 40)
+    assert len(pool) == 4 and pool.shape == (40, 40)
+    for k in range(4):
+        indptr, indices, data = pool.host[k]
+        # a band permuted symmetrically: 5 a row but at the two ends
+        assert sorted(np.diff(indptr)) == [3, 3, 4, 4] + [5] * 36
+        assert len(data) == len(indices) == 194
+        a = pool.csr(k)
+        assert np.array_equal(np.asarray(a.indices), indices)
+    # the value sets of one pattern share it; the patterns differ
+    assert pool.host[0][1] is pool.host[2][1]
+    assert not np.array_equal(pool.host[0][1], pool.host[1][1])
+    monkeypatch.setattr(matrices, "GEN_DIR", str(tmp_path))
+    with pytest.raises(FileNotFoundError, match="no generator 'band'"):
+        matrices.structure(cfg, 40, 0)
+    with pytest.raises(ValueError, match="not a module name"):
+        matrices.generator("../gen/band")
+
+
 def test_least_bytes_of_a_hand_counted_product():
     # A = [[1 . . 2]    A @ A: row 0 = 1*A0 + 2*A3 -> 2 + 1 = 3 partial
     #      [. . 3 .]    products, two of them in column 0; row 1 = 3*A2
@@ -66,6 +132,12 @@ def test_least_bytes_of_a_hand_counted_product():
     assert work.least_bytes(4, 5, 4, 5, 4, 6, products) == \
         (20 + 40) * 2 + (20 + 48) + 2 * 8 * 7
     assert work.flops(products) == 14
+    # per row: 3, 1, 1, 2; and the window's mean over its operations
+    assert window_work.row_products(indptr, indices).tolist() == [3, 1, 1, 2]
+    run = NS(operands=[(indptr, indices, 6), (indptr, indices, 4)])
+    assert window_work.least(run) == (
+        (2 * work.least_bytes(4, 5, 4, 5, 4, 6, 7) - 2 * 8) / 2, 14)
+    assert window_work.least(NS(operands=[])) == (None, None)
 
 
 def test_peaks_refuse_an_unknown_chip():
@@ -142,3 +214,17 @@ def test_benchmark_json_names_its_files():
     for m in bench["end_to_end"] + bench["per_layer"]:
         assert callable(run.reader(m["name"]))
         assert set(m.get("workloads", cells)) <= cells
+
+
+def test_each_pair_of_config_and_traffic_is_one_cell():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        cells = json.load(f)["workloads"]
+    pairs = [(w["config"], w["traffic"]) for w in cells]
+    assert len(set(pairs)) == len(pairs)
+
+
+def test_the_four_chip_mix_is_the_one_chip_mix():
+    # m133.serve-b8x4 and m133.serve-b8 differ only by the mesh
+    mixes = [json.load(open(os.path.join(BENCH, "traffic", t + ".json")))
+             for t in ("serve_closed", "serve_closed_4chips")]
+    assert mixes[0] == mixes[1]

@@ -3,7 +3,8 @@ catch: each planted under the timed path has to make ``correct`` false.
 
 A rehearsal runs the whole of a run but the look for a chip, at a small
 size on the CPU with the Pallas kernels in interpret mode.  Each run is
-its own process, as the benchmark's runs are."""
+its own process, as the benchmark's runs are.  A cell on four chips
+rehearses on four virtual CPU devices."""
 import json
 import os
 import subprocess
@@ -13,7 +14,16 @@ import pytest
 
 from conftest import BENCH, ROOT
 
-CELLS = ["m133.a2", "m133.serve-b8"]
+CELLS = ["m133.a2", "m133.serve-b8", "m133.serve-b8x4"]
+CHIPS = {"m133.serve-b8x4": 4}
+# what a CPU trace can give of the program's per-layer metrics: its host
+# spans, but no device plane, so no device time or idle
+PROGRAM_METRICS = {
+    "m133.a2": ["driver.prep_ms.product", "output.assemble_ms.product"],
+    "m133.serve-b8": ["driver.prep_ms.serve", "output.assemble_ms.serve",
+                      "serve.queue_ms"]}
+PROGRAM_METRICS["m133.serve-b8x4"] = PROGRAM_METRICS["m133.serve-b8"]
+LANES = {"m133.a2": 1, "m133.serve-b8": 8, "m133.serve-b8x4": 2}
 
 
 def run(workload, *, fault=None, seconds=0.5, trace=0):
@@ -29,6 +39,9 @@ def run(workload, *, fault=None, seconds=0.5, trace=0):
               "--seconds", str(seconds), "--trace", str(trace),
               "--rehearse"])
     env = dict(os.environ, JAX_PLATFORMS="cpu")
+    if workload in CHIPS:
+        env["XLA_FLAGS"] = (env.get("XLA_FLAGS", "") + " --xla_force_host_"
+                            f"platform_device_count={CHIPS[workload]}")
     p = subprocess.run([sys.executable, "-c", code], cwd=ROOT, env=env,
                        capture_output=True, text=True, timeout=600)
     assert p.returncode == 0, p.stderr[-3000:]
@@ -43,8 +56,12 @@ def test_rehearsal_runs_each_cell(workload):
     assert result["failed"] == 0 and result["attempted"] >= 1
     assert result["metrics"] == {}   # no CPU number under a metric name
     assert result["device"]["platform"] == "cpu"
+    assert result["device"]["count"] == CHIPS.get(workload, 1)
     assert list(result)[-1] == "checks"
     assert "programs traced in the window: 0, compiled: 0" in p.stdout
+    assert set(PROGRAM_METRICS[workload]) <= set(result["metrics_read"])
+    # a product is one lane; a flush's 8 lanes go to each chip in turn
+    assert f"engine calls of [{LANES[workload]}] lanes" in p.stdout
 
 
 @pytest.mark.parametrize("fault", ["altered", "unchanged", "half",

@@ -25,6 +25,9 @@ SPANS = {"repro.engine", "repro.spz.prep", "repro.spz.groups",
          "repro.spz.unpack", "repro.spz.assemble", "repro.serve.submit",
          "repro.serve.flush", "repro.serve.batch", "repro.serve.plan",
          "repro.serve.check", "repro.shard.assemble"}
+# the readers of the program's spans and scopes (run.program)
+PROGRAM_READERS = {"driver.prep_ms", "driver.idle_ms", "output.assemble_ms",
+               "device.expand_ms", "device.sort_merge_ms", "serve.queue_ms"}
 
 
 @pytest.fixture(scope="module")
@@ -120,14 +123,17 @@ def test_chip_metrics_per_product_and_per_flush(chip):
 
 
 def _readers(path):
-    """Every per-layer reader of BENCHMARK.json on a trace, for a run of
-    two operations with fixed host numbers."""
+    """Every per-layer reader of BENCHMARK.json that reads ``run.trace``
+    (the first benchmark's), for a run of two operations with fixed host
+    numbers."""
     run = drive.Run(peak=work.peaks("TPU v5 lite"))
     run.trace = trace_reduce.reduce_file(path)
     run.ops = [drive.Op(0, plan_s=0.001), drive.Op(1, plan_s=0.002)]
     run.window_s, run.least_bytes, run.least_flops = 1.0, 1e6, 2e6
     with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
-        names = [m["name"] for m in json.load(f)["per_layer"]]
+        names = [m["name"] for m in json.load(f)["per_layer"]
+                 if m["name"] not in PROGRAM_READERS
+                 and m["name"].rsplit(".", 1)[0] not in PROGRAM_READERS]
     return {n: bench_run.reader(n)(run) for n in names}
 
 
@@ -148,6 +154,64 @@ def test_existing_readers_read_a_trace_with_program_spans():
     assert got["output.tail_ms"] == pytest.approx(16.0179795)
     assert got["device.idle_share.product"] == pytest.approx(
         89.67993934997212)
+
+
+def _bench():
+    with open(os.path.join(ROOT, "BENCHMARK.json")) as f:
+        return json.load(f)
+
+
+def test_run_program_from_the_committed_trace():
+    run = drive.Run()
+    bench_run.read_trace(run, PROGRAM)
+    assert run.trace.gaps == trace_reduce.reduce_file(PROGRAM).gaps
+    want = pt.metrics(pt.reduce_file(PROGRAM))
+    # the trace holds products, so each reader reads per product
+    assert want["unit"] == "product"
+    got = {}
+    for m in _bench()["per_layer"]:
+        quantity = m["name"].rsplit(".", 1)[0]
+        if quantity in PROGRAM_READERS or m["name"] in PROGRAM_READERS:
+            got[m["name"]] = bench_run.reader(m["name"])(run)
+    assert len(got) == 11
+    for name, value in got.items():
+        quantity = name if name in PROGRAM_READERS else name.rsplit(".", 1)[0]
+        assert value == want.get(quantity), name
+    assert got["device.expand_ms.product"] > 0
+    assert got["serve.queue_ms"] is None     # no flush is the unit
+
+
+def test_new_readers_read_nothing_without_a_trace():
+    run = drive.Run()
+    run.ops = [drive.Op(0, plan_s=0.001)]
+    for m in _bench()["per_layer"]:
+        if m["name"] in PROGRAM_READERS or \
+                m["name"].rsplit(".", 1)[0] in PROGRAM_READERS:
+            assert bench_run.reader(m["name"])(run) is None, m["name"]
+
+
+def test_traced_result_line_of_the_serve_cell():
+    """``result_line`` under ``--trace 1`` on the committed trace: the
+    new per-layer metrics, and idle gaps labelled by the program."""
+    run = drive.Run(peak=work.peaks("TPU v5 lite"))
+    bench_run.read_trace(run, PROGRAM)
+    run.ops = [drive.Op(0), drive.Op(1)]
+    run.flushes = [NS(wall_s=1.0)]
+    chip0 = NS(platform="tpu", device_kind="TPU v5 lite")
+    spec = bench_run.cell_spec("m133.serve-b8x4")
+    args = NS(trace=1, rehearse=False)
+    line = bench_run.result_line(args, spec, run, [None], {
+        "extra_entries": 0, "value_err": 0.0}, (chip0, [chip0] * 4, 7))
+    assert line["correct"] is True and line["device"]["count"] == 4
+    for name in ("driver.prep_ms.serve", "driver.idle_ms.serve",
+                 "output.assemble_ms.serve", "device.expand_ms.serve",
+                 "device.sort_merge_ms.serve"):
+        assert line["metrics"][name]["value"] > 0, name
+        assert line["metrics"][name]["unit"] == "ms"
+    assert line["breakdown"]["idle_gaps"] == [
+        [label, sec] for label, sec in run.program.gaps[:bench_run.TOP]]
+    assert line["breakdown"]["idle_gaps"][0][0] == \
+        "bench.submit/repro.spz.assemble"
 
 
 def test_first_trace_has_no_program_spans():
@@ -216,6 +280,32 @@ def test_made_up_trace():
     assert m["serve.queue_ms"] == pytest.approx(20e-6)
     assert m["device.expand_ms"] == pytest.approx(100e-6)
     assert m["device.sort_merge_ms"] == pytest.approx(50e-6)
+
+
+def test_two_devices_add_their_scope_times():
+    """A second device running the same operations 20 ns later: each
+    scope's time doubles, the device is idle where neither is busy, and
+    each device's busy time is its own."""
+    fake = _fake()
+    second = NS(name="/device:TPU:1", lines=[
+        NS(name=line.name, events=[
+            _ev(e.name, e.start_ns + 20, e.duration_ns)
+            for e in line.events]) for line in fake.planes[1].lines])
+    fake.planes.append(second)
+    r = pt.reduce_profile(fake, HLO)
+    one = pt.reduce_profile(_fake(), HLO)
+    assert r.scope_s == {k: pytest.approx(2 * v)
+                         for k, v in one.scope_s.items()}
+    assert r.device_busy_s == {"/device:TPU:0": pytest.approx(160e-9),
+                               "/device:TPU:1": pytest.approx(160e-9)}
+    # busy 250-370, 380-390, 400-470
+    assert sorted(s for _, s in r.gaps) == pytest.approx(
+        [10e-9, 10e-9, 250e-9, 530e-9])
+    m = pt.metrics(r)
+    assert m["device.expand_ms"] == pytest.approx(200e-6)
+    assert m["device.sort_merge_ms"] == pytest.approx(100e-6)
+    # idle inside 200-600: 200-250, 370-380, 390-400, 470-600
+    assert m["driver.idle_ms"] == pytest.approx(200e-6)
 
 
 def test_runtime_event_joins_the_label():
