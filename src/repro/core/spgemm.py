@@ -140,40 +140,43 @@ def spgemm_scl_hash(A: CSR, B: CSR) -> CSR:
 def esc_core_impl(a_indptr, a_idx, a_val, b_indptr, b_idx, b_val,
                    cap_products: int, n_rows: int, n_cols: int):
     nnz_a_cap = a_idx.shape[0]
-    # --- expansion: product p belongs to A-entry t = searchsorted(Wcum, p)
-    a_rows = row_ids_from_indptr(a_indptr, nnz_a_cap)
-    blen = b_indptr[1:] - b_indptr[:-1]
-    nnz_a = a_indptr[-1]
-    t_valid = jnp.arange(nnz_a_cap) < nnz_a
-    j_of_t = jnp.where(t_valid, a_idx, 0)
-    w_t = jnp.where(t_valid, blen[j_of_t], 0)
-    wcum = jnp.cumsum(w_t)
-    total_work = wcum[-1]
-    p = jnp.arange(cap_products, dtype=jnp.int32)
-    t_of_p = jnp.searchsorted(wcum, p, side="right").astype(jnp.int32)
-    t_of_p = jnp.clip(t_of_p, 0, nnz_a_cap - 1)
-    p_valid = p < total_work
-    base = jnp.where(t_of_p > 0, wcum[t_of_p - 1], 0)
-    s_of_p = b_indptr[j_of_t[t_of_p]] + (p - base)
-    s_of_p = jnp.clip(s_of_p, 0, b_idx.shape[0] - 1)
-    prod_row = jnp.where(p_valid, a_rows[t_of_p], n_rows)
-    prod_col = jnp.where(p_valid, b_idx[s_of_p], n_cols)
-    prod_val = jnp.where(p_valid, a_val[t_of_p] * b_val[s_of_p], 0.0)
-    # --- sort by (row, col): two stable passes (the radix-sort analogue)
-    o1 = jnp.argsort(prod_col, stable=True)
-    r1, c1, v1 = prod_row[o1], prod_col[o1], prod_val[o1]
-    o2 = jnp.argsort(r1, stable=True)
-    r2, c2, v2 = r1[o2], c1[o2], v1[o2]
-    # --- compress: accumulate duplicate (row, col)
-    first = (r2 != jnp.roll(r2, 1)) | (c2 != jnp.roll(c2, 1))
-    first = first.at[0].set(True)
-    seg = jnp.cumsum(first.astype(jnp.int32)) - 1
-    out_v = jax.ops.segment_sum(v2, seg, num_segments=cap_products)
-    pos = seg
-    out_r = jnp.full(cap_products, n_rows, jnp.int32).at[pos].set(r2.astype(jnp.int32))
-    out_c = jnp.full(cap_products, n_cols, jnp.int32).at[pos].set(c2.astype(jnp.int32))
-    valid_out = (out_r < n_rows) & (out_v != 0.0)
-    n_out = jnp.sum(valid_out, dtype=jnp.int32)
+    with jax.named_scope(trace.ESC_EXPAND):
+        # product p belongs to A-entry t = searchsorted(Wcum, p)
+        a_rows = row_ids_from_indptr(a_indptr, nnz_a_cap)
+        blen = b_indptr[1:] - b_indptr[:-1]
+        nnz_a = a_indptr[-1]
+        t_valid = jnp.arange(nnz_a_cap) < nnz_a
+        j_of_t = jnp.where(t_valid, a_idx, 0)
+        w_t = jnp.where(t_valid, blen[j_of_t], 0)
+        wcum = jnp.cumsum(w_t)
+        total_work = wcum[-1]
+        p = jnp.arange(cap_products, dtype=jnp.int32)
+        t_of_p = jnp.searchsorted(wcum, p, side="right").astype(jnp.int32)
+        t_of_p = jnp.clip(t_of_p, 0, nnz_a_cap - 1)
+        p_valid = p < total_work
+        base = jnp.where(t_of_p > 0, wcum[t_of_p - 1], 0)
+        s_of_p = b_indptr[j_of_t[t_of_p]] + (p - base)
+        s_of_p = jnp.clip(s_of_p, 0, b_idx.shape[0] - 1)
+        prod_row = jnp.where(p_valid, a_rows[t_of_p], n_rows)
+        prod_col = jnp.where(p_valid, b_idx[s_of_p], n_cols)
+        prod_val = jnp.where(p_valid, a_val[t_of_p] * b_val[s_of_p], 0.0)
+    with jax.named_scope(trace.ESC_SORT):
+        # by (row, col): two stable passes (the radix-sort analogue)
+        o1 = jnp.argsort(prod_col, stable=True)
+        r1, c1, v1 = prod_row[o1], prod_col[o1], prod_val[o1]
+        o2 = jnp.argsort(r1, stable=True)
+        r2, c2, v2 = r1[o2], c1[o2], v1[o2]
+    with jax.named_scope(trace.ESC_COMPRESS):
+        # accumulate duplicate (row, col)
+        first = (r2 != jnp.roll(r2, 1)) | (c2 != jnp.roll(c2, 1))
+        first = first.at[0].set(True)
+        seg = jnp.cumsum(first.astype(jnp.int32)) - 1
+        out_v = jax.ops.segment_sum(v2, seg, num_segments=cap_products)
+        pos = seg
+        out_r = jnp.full(cap_products, n_rows, jnp.int32).at[pos].set(r2.astype(jnp.int32))
+        out_c = jnp.full(cap_products, n_cols, jnp.int32).at[pos].set(c2.astype(jnp.int32))
+        valid_out = (out_r < n_rows) & (out_v != 0.0)
+        n_out = jnp.sum(valid_out, dtype=jnp.int32)
     return out_r, out_c, out_v, valid_out, n_out
 
 
@@ -187,11 +190,16 @@ def spgemm_esc(A: CSR, B: CSR, cap_products: int | None = None) -> CSR:
     """Vectorized Expand-Sort-Compress SpGEMM (the vec-radix analogue)."""
     if cap_products is None:
         cap_products = int(max(16, row_work(A, B).sum()))
-    r, c, v, valid, _ = _esc_core(A.indptr, A.indices, A.data,
-                                  B.indptr, B.indices, B.data,
-                                  cap_products, A.n_rows, B.n_cols)
-    r, c, v, valid = map(np.asarray, (r, c, v, valid))
-    return csr_from_coo(r[valid], c[valid], v[valid], (A.n_rows, B.n_cols))
+    with trace.span(trace.ESC_DEVICE, products=cap_products, rows=A.n_rows):
+        out = jax.block_until_ready(_esc_core(
+            A.indptr, A.indices, A.data, B.indptr, B.indices, B.data,
+            cap_products, A.n_rows, B.n_cols))
+    with trace.span(trace.ESC_FETCH) as s:
+        r, c, v, valid, n_out = map(np.asarray, out)
+        s.set_metadata(bytes=sum(x.nbytes for x in (r, c, v, valid, n_out)),
+                       entries=int(n_out))
+    with trace.span(trace.ESC_ASSEMBLE):
+        return csr_from_coo(r[valid], c[valid], v[valid], (A.n_rows, B.n_cols))
 
 
 # ---------------------------------------------------------------------------

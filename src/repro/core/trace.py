@@ -45,6 +45,19 @@ Spans, where they open, their stats, and what reads them
     ``_spz_batched``'s per-row loop with ``csr_from_coo``.  ``nnz_out``
     (the output's nonzeros, over all lanes).  Read as
     ``output.assemble_ms``.
+``repro.esc.device``
+    ``spgemm_esc``: the jitted expand-sort-compress program's dispatch,
+    waited on until it completes, so the next span times the copy alone.
+    ``products`` (``cap_products``, the program's static length),
+    ``rows``.
+``repro.esc.fetch``
+    the copy of its outputs to the host: rows, columns, values and the
+    valid mask, each ``cap_products`` long, and the valid count.  ``bytes`` (what is copied),
+    ``entries`` (the valid output count).  Read as ``esc.fetch_ms`` and,
+    from ``bytes``, ``esc.fetch_mb``.
+``repro.esc.assemble``
+    the host COO->CSR of the valid entries (``csr_from_coo``) and its
+    upload.  Read as ``esc.assemble_ms``.
 ``repro.serve.submit``
     ``SpGemmService.submit`` (a flush it triggers runs inside it).
     ``request`` (the id).
@@ -66,7 +79,11 @@ Device scopes (``jax.named_scope``, in each operation's HLO
 ``spz.expand`` (``_fused_expand``) and ``spz.sort_merge``
 (``stream.fused_sort_merge``), read as ``device.expand_ms`` and
 ``device.sort_merge_ms``.  Each Pallas kernel of the spz path is named
-after its kernel function (``kernels/_network.stream_call``).
+after its kernel function (``kernels/_network.stream_call``).  The ESC
+program ``esc_core_impl`` has ``esc.expand`` (the partial products by a
+search of A's work prefix), ``esc.sort`` (the two stable argsorts) and
+``esc.compress`` (the segment sum and scatters); ``bench/run.py`` does
+not split by them yet.
 """
 from __future__ import annotations
 
@@ -86,9 +103,15 @@ SERVE_BATCH = "repro.serve.batch"
 SERVE_PLAN = "repro.serve.plan"
 SERVE_CHECK = "repro.serve.check"
 SHARD_ASSEMBLE = "repro.shard.assemble"
+ESC_DEVICE = "repro.esc.device"
+ESC_FETCH = "repro.esc.fetch"
+ESC_ASSEMBLE = "repro.esc.assemble"
 
 EXPAND = "spz.expand"
 SORT_MERGE = "spz.sort_merge"
+ESC_EXPAND = "esc.expand"
+ESC_SORT = "esc.sort"
+ESC_COMPRESS = "esc.compress"
 
 
 # ``with span(NAME, **stats) as s:``; stats known only inside the span

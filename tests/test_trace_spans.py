@@ -174,3 +174,42 @@ def test_stat_helpers_avoid_the_profilers_separators():
     assert trace.bucket(key) == "48x48@48x48/128/256"
     for text in (trace.ids([1, 2]), trace.bucket(key)):
         assert "," not in text and "#" not in text and "=" not in text
+
+
+def test_esc_product_spans_once_per_product_and_not_for_spz(tmp_path,
+                                                            powerlaw):
+    A = powerlaw
+    esc = dp.plan(A, A, engine="esc")
+    dp.execute(esc, A, A)   # compile outside the trace
+    _product(A)
+    outs, spans = _record(tmp_path, lambda: [
+        dp.execute(esc, A, A), _product(A), dp.execute(esc, A, A)])
+    products = int(sg.row_work(A, A).sum())
+    engines = _named(spans, trace.ENGINE)
+    assert [e["stats"]["engine"] for e in engines] == ["esc", "spz", "esc"]
+    for engine, out in zip(engines, outs):
+        inner = [_within(spans, name, engine) for name in (
+            trace.ESC_DEVICE, trace.ESC_FETCH, trace.ESC_ASSEMBLE)]
+        if engine["stats"]["engine"] == "spz":
+            assert inner == [[], [], []]
+            continue
+        (device,), (fetch,), (assemble,) = inner
+        assert device["end"] <= fetch["start"]
+        assert fetch["end"] <= assemble["start"]
+        assert device["stats"] == {"products": products, "rows": A.n_rows}
+        # rows, columns and values (4 bytes each), the valid mask (1) and
+        # the int32 count, each of the program's static length
+        assert fetch["stats"] == {"bytes": 13 * products + 4,
+                                  "entries": int(np.asarray(out.indptr)[-1])}
+    for name in (trace.ESC_DEVICE, trace.ESC_FETCH, trace.ESC_ASSEMBLE):
+        assert len(_named(spans, name)) == 2
+
+
+def test_esc_program_names_its_phases():
+    shapes = [(9,), (32,), (32,), (9,), (32,), (32,)]
+    args = [jax.ShapeDtypeStruct(s, np.float32 if i % 3 == 2 else np.int32)
+            for i, s in enumerate(shapes)]
+    text = sg._esc_core.lower(*args, cap_products=128, n_rows=8,
+                              n_cols=8).compile().as_text()
+    for scope in (trace.ESC_EXPAND, trace.ESC_SORT, trace.ESC_COMPRESS):
+        assert f'op_name="jit(esc_core_impl)/{scope}/' in text, scope
